@@ -21,12 +21,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import tuning_cache
 from repro.core import KernelTuner
 from repro.kernels.api import divisors, get_spec, tuned_kernel
-from repro.kernels.common import (cdiv, default_interpret, require_tiling,
-                                  tpu_compiler_params)
+from repro.kernels.common import cdiv, default_interpret, require_tiling
 
 
 # -- 1. the kernel body: a row-blocked fused scale-add ----------------------
@@ -85,7 +85,8 @@ def saxpy2d_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
                   pl.BlockSpec((bm, n), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
-        compiler_params=tpu_compiler_params(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(a, b)
 

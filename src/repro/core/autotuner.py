@@ -55,9 +55,12 @@ class KernelStaticInfo:
 
     mix: InstructionMix
     occupancy: Optional[TpuOccupancy] = None
+    # every block obeys the TPU block rule (`kernels.common.tile_legal`)
+    tiles_legal: bool = True
 
     def feasible(self) -> bool:
-        return self.occupancy is None or self.occupancy.fits_vmem
+        return self.tiles_legal and (self.occupancy is None
+                                     or self.occupancy.fits_vmem)
 
     def static_time(self, model: CostModel) -> float:
         """Predicted seconds; infeasible configs get +inf."""

@@ -200,6 +200,8 @@ def _find_close(s: str) -> int:
 
 
 _DIRECTION_RE = re.compile(r"direction=([A-Z]+)")
+# XLA's own loop analysis, attached to the while instruction itself.
+_KNOWN_TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 
 
 def _const_value(comp: HloComputation, operand: str) -> Optional[int]:
@@ -255,15 +257,23 @@ def _root_bound(comp: HloComputation, ins: Optional[HloInstruction],
     return None
 
 
-def _trip_count(comp: HloComputation) -> Tuple[Optional[int], bool]:
-    """(trip count, exact) of a while-condition computation.
+def _trip_count(comp: HloComputation,
+                while_line: str = "") -> Tuple[Optional[int], bool]:
+    """(trip count, exact) of a while loop, given its condition
+    computation and the while instruction's own line.
 
-    Exact path: the bound is recovered from the compare feeding the
-    ROOT (``compare(iv, constant(16)), direction=LT`` -> 16), so an
-    unrelated larger constant elsewhere in the condition cannot
-    overcount the loop.  Fallback: the old max-s32[]-constant heuristic
-    with ``exact=False`` — callers count it in ``unknown_loops``.
+    Exact paths, in order: the ``known_trip_count`` XLA records in the
+    while's ``backend_config`` (the condition's compare is then often
+    wrapped in a fusion), then the bound recovered from the compare
+    feeding the condition's ROOT (``compare(iv, constant(16)),
+    direction=LT`` -> 16), so an unrelated larger constant elsewhere in
+    the condition cannot overcount the loop.  Fallback: the
+    max-s32[]-constant heuristic with ``exact=False`` — callers count
+    it in ``unknown_loops``.
     """
+    known = _KNOWN_TRIP_RE.search(while_line)
+    if known:
+        return int(known.group(1)), True
     root = None
     for ins in comp.instructions:
         if ins.line.lstrip().startswith("ROOT"):
@@ -310,7 +320,8 @@ def _propagate_multipliers(mod: HloModule) -> None:
                     cond_name = mcond.group(1)
                 tc, exact = None, False
                 if cond_name and cond_name in mod.computations:
-                    tc, exact = _trip_count(mod.computations[cond_name])
+                    tc, exact = _trip_count(mod.computations[cond_name],
+                                            ins.line)
                 if tc is None:
                     mod.unknown_loops += 1
                     trip = 1.0

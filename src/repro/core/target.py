@@ -11,10 +11,11 @@ active.  Resolution order:
 2. the ``REPRO_TUNING_TARGET`` environment variable (any name
    `repro.core.hw.resolve_target` accepts — a TPU table entry like
    ``tpu-v5p`` or a paper Table I GPU like ``kepler_k20``),
-3. best-effort auto-detection from ``jax.devices()[0].device_kind``
-   (memoized; CPU/GPU backends simply don't match),
-4. the v5e fallback, so behaviour without any configuration is
-   identical to the pre-registry stack.
+3. auto-detection from ``jax.devices()[0].device_kind`` on a TPU
+   backend (memoized); a TPU whose ``device_kind`` is not in the table
+   raises instead of being analyzed as some other chip,
+4. on any other backend, v5e as the analysis target (there is no chip
+   to detect; the tuner still ranks for the default TPU).
 
 Because tuning-cache keys and the dispatch memo already carry the full
 spec fingerprint (`repro.tuning_cache.keys.fingerprint_spec`), switching
@@ -25,7 +26,6 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-import logging
 import os
 from typing import Any, Iterator, Optional, Union
 
@@ -36,8 +36,6 @@ __all__ = ["ENV_TARGET", "default_target", "unscoped_default",
            "on_default_target_change"]
 
 ENV_TARGET = "REPRO_TUNING_TARGET"
-
-_log = logging.getLogger(__name__)
 
 # Scoped override (use_target).  A ContextVar, not a module global:
 # concurrent threads / async tasks each see their own scope, so one
@@ -79,30 +77,27 @@ def on_default_target_change(hook) -> Any:
 
 
 def detect_target() -> Optional[ChipSpec]:
-    """Best-effort chip detection from the local jax backend.
+    """The spec of the local TPU, from the jax backend.
 
-    Returns the matching spec, or ``None`` when there is no TPU
-    (CPU/GPU backend) or jax is unavailable.  The first call may
-    initialize the jax backend; results — including failures — are
-    memoized for the life of the process.
+    Returns ``None`` on a non-TPU backend (CPU/GPU: no chip to tune
+    for).  On a TPU backend an unknown ``device_kind`` raises
+    ``KeyError`` — ranking for the wrong chip would be silent.  The
+    first call initializes the jax backend; the result is memoized for
+    the life of the process.
     """
     global _detected
     if _detected is None:
-        spec = None
-        try:
-            import jax
-            devices = jax.devices()
-            if devices:
-                spec = resolve_target(devices[0].device_kind)
-        except Exception as e:     # no backend / unknown kind: fall through
-            _log.debug("target auto-detection failed: %s", e)
+        import jax
+        device = jax.devices()[0]
+        spec = (resolve_target(device.device_kind)
+                if device.platform == "tpu" else None)
         _detected = (spec,)
     return _detected[0]
 
 
 def unscoped_default() -> ChipSpec:
     """The process-default chip, *ignoring* any `use_target` scope:
-    explicit pin > environment > autodetect > v5e.
+    explicit pin > environment > detected TPU > v5e (non-TPU backend).
 
     This is what a ``spec=None`` dispatch resolves to whenever no scoped
     override is active — the frozen dispatch tables

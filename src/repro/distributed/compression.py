@@ -44,10 +44,7 @@ def init_ef_state(params) -> Dict:
 def _compress_one(g, r, axis_name: str):
     """Inside shard_map over the pod axis: quantize local (g - psum g/n
     ... ), psum, dequantize, error-feedback."""
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:                                # jax 0.4.x: psum of ones
-        n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     target = g.astype(jnp.float32) + r
     q, scale = quantize_int8(target)
     # integer psum keeps the payload 1 byte on the wire (widened for sum)
@@ -69,8 +66,6 @@ def ef_compress_grads(grads, opt_state: Dict, mesh):
         opt_state = dict(opt_state)
         opt_state["ef"] = init_ef_state(grads)
 
-    other = frozenset(a for a in mesh.axis_names if a != "pod")
-
     def per_pod(g_tree, r_tree):
         out = jax.tree.map(
             lambda g, r: _compress_one(g, r, "pod"), g_tree, r_tree)
@@ -80,15 +75,9 @@ def ef_compress_grads(grads, opt_state: Dict, mesh):
                              is_leaf=lambda x: isinstance(x, tuple))
         return g_new, r_new
 
-    if hasattr(jax, "shard_map"):        # jax >= 0.6 top-level API
-        fn = jax.shard_map(per_pod, mesh=mesh,
-                           in_specs=(P(), P()), out_specs=(P(), P()),
-                           check_vma=False, axis_names={"pod"})
-    else:                                # jax 0.4.x experimental API
-        from jax.experimental.shard_map import shard_map as _shard_map
-        fn = _shard_map(per_pod, mesh=mesh,
-                        in_specs=(P(), P()), out_specs=(P(), P()),
-                        check_rep=False, auto=other)
+    fn = jax.shard_map(per_pod, mesh=mesh,
+                       in_specs=(P(), P()), out_specs=(P(), P()),
+                       check_vma=False, axis_names={"pod"})
     g_new, r_new = fn(grads, opt_state["ef"]["residual"])
     opt_state = dict(opt_state)
     opt_state["ef"] = {"residual": r_new}

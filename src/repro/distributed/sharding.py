@@ -17,7 +17,8 @@ This gives the production behaviours for free:
 * DP over pods: ``batch -> ("pod", "data")`` groups both axes.
 
 Rule tables are plain tuples so hillclimb variants (e.g. sequence-
-sharded decode caches) are one-line swaps recorded in EXPERIMENTS.md.
+sharded decode caches) are one-line swaps (`repro.launch.dryrun`
+variants).
 """
 from __future__ import annotations
 
@@ -76,7 +77,7 @@ ACT_RULES: Rules = (
 # Megatron-style sequence parallelism: the residual stream between
 # blocks is sharded over the model axis (16x smaller scan-boundary
 # saves under remat; GSPMD all-gathers at attention/MLP entry and
-# reduce-scatters after).  Hillclimb variant — see EXPERIMENTS.md §Perf.
+# reduce-scatters after).  Dry-run variant ``sp`` (`launch/dryrun.py`).
 ACT_RULES_SP: Rules = tuple(
     (("residual_seq", ("model",)) if name == "residual_seq"
      else (name, cands))

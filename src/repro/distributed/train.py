@@ -132,13 +132,19 @@ def make_train_step(model: "Model", opt_cfg: AdamWConfig,
 def make_serve_fns(model: "Model", mesh=None,
                    step_cfg: TrainStepConfig = TrainStepConfig()
                    ) -> Tuple[Callable, Callable]:
-    """(prefill, decode_step) closures with the Sharder bound."""
+    """(prefill, decode_step) closures with the Sharder bound.
+
+    ``prefill(params, batch, max_len=0)`` sizes the decode cache for
+    ``max_len`` positions (at least the prompt): a server that decodes
+    ``gen`` tokens passes ``prompt_len + gen``, so no decode step
+    writes past the cache.  Bind it before ``jax.jit`` (it is a shape).
+    """
     shd = Sharder(mesh, act_rules=step_cfg.act_rules,
                   cache_rules=step_cfg.cache_rules,
                   weight_rules=step_cfg.weight_rules)
 
-    def prefill(params, batch):
-        return model.prefill(params, batch, shd)
+    def prefill(params, batch, max_len: int = 0):
+        return model.prefill(params, batch, shd, max_len=max_len)
 
     def decode_step(params, cache, token):
         return model.decode_step(params, cache, token, shd)

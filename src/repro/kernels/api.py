@@ -47,6 +47,7 @@ import contextvars
 import dataclasses
 import functools
 import inspect
+import itertools
 import logging
 import threading
 from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
@@ -93,10 +94,11 @@ class Divisors:
     """A tunable axis whose candidates must tile a signature dimension.
 
     At problem-construction time the candidate list is filtered to the
-    values that divide ``signature[dim]`` (BlockSpec-exact tiling); if
-    none divide, the dimension itself is the only candidate.  The
-    derived fallback param is the largest surviving candidate — the
-    same "largest divisor" rule the hand-written ops used.
+    values that divide ``signature[dim]`` (BlockSpec-exact tiling), and
+    the dimension itself joins them when none is lane-aligned
+    (`kernels.common.pick_divisor_candidates`).  The derived fallback
+    param is the largest surviving candidate — the same "largest
+    divisor" rule the hand-written ops used.
     """
 
     dim: str
@@ -621,8 +623,9 @@ class KernelSpec:
                        analyze: Callable[[Dict[str, Any]], KernelStaticInfo],
                        sig: Dict[str, Any]) -> Dict[str, Any]:
         """Largest-divisor fallback over one axis set, backed off
-        (largest block first) until ``analyze`` reports VMEM fit.
-        Memoized per (variant tag, signature)."""
+        (largest block first) until ``analyze`` reports it feasible
+        (fits VMEM, every block legal).  Memoized per (variant tag,
+        signature)."""
         try:
             memo_key = (tag, tuple(sorted(sig.items())))
             hit = self._fallback_cache.get(memo_key)
@@ -647,7 +650,19 @@ class KernelSpec:
                 while not analyze(current()).feasible():
                     movable = [n for n in idx if idx[n] > 0]
                     if not movable:
-                        break            # smallest config; nothing left
+                        # backing off one axis can pass through blocks
+                        # the TPU block rule refuses: take the first
+                        # feasible config, largest first, if any
+                        for ix in itertools.product(
+                                *(range(len(v) - 1, -1, -1)
+                                  for v in cands.values())):
+                            idx = dict(zip(cands, ix))
+                            if analyze(current()).feasible():
+                                break
+                        else:
+                            idx = {name: len(v) - 1
+                                   for name, v in cands.items()}
+                        break
                     biggest = max(movable, key=lambda n: cands[n][idx[n]])
                     idx[biggest] -= 1
             except Exception:

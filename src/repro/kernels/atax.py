@@ -23,7 +23,7 @@ from repro.core.search import SearchSpace
 from repro.kernels.api import cuda_profile, divisors, get_spec, tuned_kernel
 from repro.kernels.common import (block_info, cdiv, default_interpret,
                                   pick_divisor_candidates, require_shape,
-                                  require_tiling, tpu_compiler_params)
+                                  require_tiling)
 from repro.kernels.ref import atax_ref
 
 __all__ = ["atax_pallas", "atax_static_info", "make_tunable_atax"]
@@ -55,6 +55,8 @@ def _atax_analysis(p, *, m: int, n: int, dtype: str = "float32"):
     return dict(
         in_blocks=[(bm, n), (n, 1)],
         out_blocks=[(n, 1)],
+        in_shapes=[(m, n), (n, 1)],
+        out_shapes=[(n, 1)],
         in_dtypes=[dtype, dtype],
         out_dtypes=[dtype],
         flops_per_step=2.0 * bm * n + 2.0 * n * bm,   # A@x then Aᵀ@t
@@ -114,7 +116,8 @@ def atax_pallas(a: jax.Array, x: jax.Array, *, bm: int = 256,
         out_specs=pl.BlockSpec((n, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), a.dtype),
         scratch_shapes=[pltpu.VMEM((n, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(a, x)
 

@@ -20,7 +20,7 @@ from repro.core.search import SearchSpace
 from repro.kernels.api import cuda_profile, divisors, get_spec, tuned_kernel
 from repro.kernels.common import (block_info, cdiv, default_interpret,
                                   pick_divisor_candidates, require_shape,
-                                  require_tiling, tpu_compiler_params)
+                                  require_tiling)
 from repro.kernels.ref import bicg_ref
 
 __all__ = ["bicg_pallas", "bicg_static_info", "make_tunable_bicg"]
@@ -52,6 +52,8 @@ def _bicg_analysis(p, *, m: int, n: int, dtype: str = "float32"):
     return dict(
         in_blocks=[(bm, n), (n, 1), (bm, 1)],
         out_blocks=[(bm, 1), (n, 1)],
+        in_shapes=[(m, n), (n, 1), (m, 1)],
+        out_shapes=[(m, 1), (n, 1)],
         in_dtypes=[dtype] * 3,
         out_dtypes=[dtype] * 2,
         flops_per_step=4.0 * bm * n,     # two mat-vec MACs over the block
@@ -111,7 +113,8 @@ def bicg_pallas(a: jax.Array, p: jax.Array, r: jax.Array, *,
         out_shape=[jax.ShapeDtypeStruct((m, 1), a.dtype),
                    jax.ShapeDtypeStruct((n, 1), a.dtype)],
         scratch_shapes=[pltpu.VMEM((n, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(a, p, r)
 

@@ -21,7 +21,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import GpuSpec, TpuSpec, dtype_bytes
 from repro.core.mix import InstructionMix
@@ -33,21 +32,11 @@ from repro.core.predict import cuda_eq6_time
 from repro.core.autotuner import KernelStaticInfo
 
 __all__ = ["cdiv", "default_interpret", "round_up", "block_info",
+           "LANE", "tile_legal",
            "BatchStaticInfo", "block_info_batch",
            "CudaStaticInfo", "cuda_info",
            "CudaBatchStaticInfo", "cuda_info_batch",
-           "pick_divisor_candidates", "CompilerParams",
-           "tpu_compiler_params", "require_tiling", "require_shape"]
-
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams around 0.5;
-# resolve whichever this jax ships so kernels work on both sides.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-
-def tpu_compiler_params(dimension_semantics: Sequence[str]) -> "CompilerParams":
-    """Version-portable `compiler_params=` value for `pl.pallas_call`."""
-    return CompilerParams(dimension_semantics=tuple(dimension_semantics))
+           "pick_divisor_candidates", "require_tiling", "require_shape"]
 
 
 def cdiv(a: int, b: int) -> int:
@@ -63,10 +52,44 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# Mosaic lays a block's trailing two dims out in (sublane, lane) tiles
+# of 8 rows x 128 lanes of 32-bit words; narrower dtypes pack more rows
+# into one tile (16 for bf16).
+LANE = 128
+
+
 def pick_divisor_candidates(n: int, candidates: Sequence[int]) -> tuple:
-    """Keep candidates that divide n (BlockSpec-exact tiling)."""
+    """Keep candidates that divide n (BlockSpec-exact tiling), plus n
+    itself when none of them is a multiple of `LANE`: the full extent
+    is a legal TPU block in any position, so the space never lacks a
+    block that compiles."""
     vals = tuple(c for c in candidates if c <= n and n % c == 0)
-    return vals or (n,)
+    if n not in vals and not any(v % LANE == 0 for v in vals):
+        vals += (n,)
+    return vals
+
+
+def tile_legal(block: Sequence, array: Sequence, dtype):
+    """The TPU block rule for one operand: the block's last dim is a
+    multiple of `LANE` or the array's extent, and its second-to-last a
+    multiple of the dtype's sublane count (8 for f32, 16 for bf16) or
+    the array's extent.  Dims may be ints or (N,) arrays; returns a
+    bool or an (N,) mask."""
+    last = np.asarray(block[-1])
+    ok = (last % LANE == 0) | (last == array[-1])
+    if len(block) >= 2:
+        sub = max(8, 32 // dtype_bytes(dtype))
+        second = np.asarray(block[-2])
+        ok = ok & ((second % sub == 0) | (second == array[-2]))
+    return ok
+
+
+def _tiles_legal(blocks, shapes, dtypes):
+    """AND of `tile_legal` over every operand (True if no shapes)."""
+    ok = np.asarray(True)
+    for b, a, d in zip(blocks, shapes or (), dtypes):
+        ok = ok & tile_legal(b, a, d)
+    return ok
 
 
 def require_tiling(kernel: str, shape: "dict", block: "dict") -> None:
@@ -107,6 +130,8 @@ def block_info(*,
                scratch_bytes: int = 0,
                mix_scale: float | None = None,
                ctrl_ops: float | None = None,
+               in_shapes: Sequence[tuple] | None = None,
+               out_shapes: Sequence[tuple] | None = None,
                spec: TpuSpec | None = None) -> KernelStaticInfo:
     """Analytic KernelStaticInfo from block shapes + per-step op counts.
 
@@ -114,6 +139,9 @@ def block_info(*,
     times the number of grid steps).  ``ctrl_ops`` overrides the
     control-op count (default: one per grid step) — kernels with an
     unroll axis amortize loop control across unrolled iterations.
+    ``in_shapes`` / ``out_shapes`` are the full arrays each block
+    tiles; given, a block that breaks the TPU block rule
+    (`tile_legal`) makes the configuration infeasible.
     ``spec=None`` analyzes for the process-default target
     (`repro.core.target.default_target`).
     """
@@ -138,7 +166,9 @@ def block_info(*,
         ctrl_ops=float(grid_steps if ctrl_ops is None else ctrl_ops),
         reg_ops=0.0,
     )
-    return KernelStaticInfo(mix=mix, occupancy=occ)
+    legal = (_tiles_legal(in_blocks, in_shapes, in_dtypes)
+             & _tiles_legal(out_blocks, out_shapes, out_dtypes))
+    return KernelStaticInfo(mix=mix, occupancy=occ, tiles_legal=bool(legal))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,13 +185,14 @@ class BatchStaticInfo:
 
     F: np.ndarray                   # (N, 7) float64
     occupancy: TpuOccupancyBatch
+    tiles_legal: np.ndarray         # (N,) bool: every block obeys the rule
 
     def __len__(self) -> int:
         return int(self.F.shape[0])
 
     @property
     def feasible(self) -> np.ndarray:
-        return self.occupancy.fits_vmem
+        return self.occupancy.fits_vmem & self.tiles_legal
 
     @property
     def pipe(self) -> np.ndarray:
@@ -182,6 +213,8 @@ def block_info_batch(*,
                      scratch_bytes=0,
                      mix_scale=None,
                      ctrl_ops=None,
+                     in_shapes: Sequence[tuple] | None = None,
+                     out_shapes: Sequence[tuple] | None = None,
                      spec: TpuSpec | None = None) -> BatchStaticInfo:
     """Vectorized `block_info`: one (N, 7) feature matrix + occupancy
     arrays for a whole config lattice in a single NumPy pass.
@@ -223,7 +256,10 @@ def block_info_batch(*,
                        dtype=np.float64)),
         col(0.0),
     ])
-    return BatchStaticInfo(F=F, occupancy=occ)
+    legal = (_tiles_legal(in_blocks, in_shapes, in_dtypes)
+             & _tiles_legal(out_blocks, out_shapes, out_dtypes))
+    return BatchStaticInfo(F=F, occupancy=occ,
+                           tiles_legal=np.broadcast_to(legal, (n,)))
 
 
 # ---------------------------------------------------------------------------

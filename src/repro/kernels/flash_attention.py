@@ -37,7 +37,7 @@ from repro.kernels.api import (KernelVariant, cuda_profile, divisors,
                                get_spec, tuned_kernel)
 from repro.kernels.common import (block_info, cdiv, default_interpret,
                                   pick_divisor_candidates, require_shape,
-                                  require_tiling, tpu_compiler_params)
+                                  require_tiling)
 from repro.kernels.ref import attention_ref
 
 __all__ = ["flash_attention_pallas", "blocked_attention_pallas",
@@ -95,6 +95,8 @@ def _flash_analysis(p, *, b: int, h: int, sq: int, skv: int, d: int,
     return dict(
         in_blocks=[(bq, d), (bkv, d), (bkv, d)],
         out_blocks=[(bq, d)],
+        in_shapes=[(sq, d), (skv, d), (skv, d)],
+        out_shapes=[(sq, d)],
         in_dtypes=[dtype] * 3,
         out_dtypes=[dtype],
         flops_per_step=4.0 * bq * bkv * d * eff,   # QK^T + PV
@@ -152,6 +154,8 @@ def _blocked_analysis(p, *, b: int, h: int, sq: int, skv: int, d: int,
     return dict(
         in_blocks=[(bq, d), (skv, d), (skv, d)],
         out_blocks=[(bq, d)],
+        in_shapes=[(sq, d), (skv, d), (skv, d)],
+        out_shapes=[(sq, d)],
         in_dtypes=[dtype] * 3,
         out_dtypes=[dtype],
         flops_per_step=4.0 * bq * skv * d,         # QK^T + PV, no discount
@@ -192,7 +196,8 @@ def blocked_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda g, i: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        compiler_params=tpu_compiler_params(("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d)
@@ -274,8 +279,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d)

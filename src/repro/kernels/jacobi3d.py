@@ -18,13 +18,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.autotuner import KernelStaticInfo, TunableKernel
 from repro.core.search import SearchSpace
 from repro.kernels.api import cuda_profile, divisors, get_spec, tuned_kernel
 from repro.kernels.common import (block_info, cdiv, default_interpret,
-                                  pick_divisor_candidates, require_tiling,
-                                  tpu_compiler_params)
+                                  pick_divisor_candidates, require_tiling)
 from repro.kernels.ref import jacobi3d_ref
 
 __all__ = ["jacobi3d_pallas", "jacobi3d_static_info",
@@ -73,6 +73,8 @@ def _jacobi3d_analysis(p, *, z: int, y: int, x: int,
     return dict(
         in_blocks=[(bz, y, x)] * 3,
         out_blocks=[(bz, y, x)],
+        in_shapes=[(z, y, x)] * 3,
+        out_shapes=[(z, y, x)],
         in_dtypes=[dtype] * 3,
         out_dtypes=[dtype],
         flops_per_step=0.0,
@@ -128,7 +130,8 @@ def jacobi3d_pallas(u: jax.Array, *, bz: int = 8,
         ],
         out_specs=pl.BlockSpec((bz, y, x), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((z, y, x), u.dtype),
-        compiler_params=tpu_compiler_params(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(u, u, u)
 

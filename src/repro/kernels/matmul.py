@@ -24,8 +24,7 @@ from repro.core.autotuner import KernelStaticInfo, TunableKernel
 from repro.core.search import SearchSpace
 from repro.kernels.api import cuda_profile, divisors, get_spec, tuned_kernel
 from repro.kernels.common import (block_info, cdiv, default_interpret,
-                                  pick_divisor_candidates, require_tiling,
-                                  tpu_compiler_params)
+                                  pick_divisor_candidates, require_tiling)
 from repro.kernels.ref import matmul_ref
 
 __all__ = ["matmul_pallas", "matmul_static_info", "make_tunable_matmul"]
@@ -55,6 +54,8 @@ def _matmul_analysis(p, *, m: int, n: int, k: int, dtype: str = "float32"):
     return dict(
         in_blocks=[(bm, bk), (bk, bn)],
         out_blocks=[(bm, bn)],
+        in_shapes=[(m, k), (k, n)],
+        out_shapes=[(m, n)],
         in_dtypes=[dtype, dtype],
         out_dtypes=[dtype],
         flops_per_step=2.0 * bm * bn * bk,
@@ -138,8 +139,8 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)
 

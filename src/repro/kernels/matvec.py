@@ -22,7 +22,7 @@ from repro.core.search import SearchSpace
 from repro.kernels.api import cuda_profile, divisors, get_spec, tuned_kernel
 from repro.kernels.common import (block_info, cdiv, default_interpret,
                                   pick_divisor_candidates, require_shape,
-                                  require_tiling, tpu_compiler_params)
+                                  require_tiling)
 from repro.kernels.ref import matvec_ref
 
 __all__ = ["matvec_pallas", "matvec_static_info", "make_tunable_matvec"]
@@ -51,6 +51,8 @@ def _matvec_analysis(p, *, m: int, n: int, dtype: str = "float32"):
     return dict(
         in_blocks=[(bm, bk), (bk, 1)],
         out_blocks=[(bm, 1)],
+        in_shapes=[(m, n), (n, 1)],
+        out_shapes=[(m, 1)],
         in_dtypes=[dtype, dtype],
         out_dtypes=[dtype],
         flops_per_step=2.0 * bm * bk,
@@ -105,7 +107,8 @@ def matvec_pallas(a: jax.Array, x: jax.Array, *,
         out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, x)
 

@@ -43,7 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.api import KernelVariant, divisors, tuned_kernel
 from repro.kernels.common import (cdiv, default_interpret, require_shape,
-                                  require_tiling, tpu_compiler_params)
+                                  require_tiling)
 from repro.kernels.ref import _MLP_ACTS, mlp_matmul_ref
 
 __all__ = ["mlp_matmul_fused_pallas", "mlp_matmul_split_pallas"]
@@ -81,6 +81,8 @@ def _fused_analysis(p, *, m: int, d: int, f: int, act: str = "silu",
     return dict(
         in_blocks=[(bm, bk), (bk, bn), (bk, bn)],
         out_blocks=[(bm, bn)],
+        in_shapes=[(m, d), (d, f), (d, f)],
+        out_shapes=[(m, f)],
         in_dtypes=[dtype] * 3,
         out_dtypes=[dtype],
         flops_per_step=4.0 * bm * bn * bk,         # two dots per step
@@ -118,8 +120,8 @@ def mlp_matmul_fused_pallas(x: jax.Array, w_gate: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_gate, w_up)
 
@@ -145,6 +147,8 @@ def _stream_analysis(p, *, m: int, d: int, f: int, act: str = "silu",
     return dict(
         in_blocks=[(bm, d), (d, bn), (d, bn)],
         out_blocks=[(bm, bn)],
+        in_shapes=[(m, d), (d, f), (d, f)],
+        out_shapes=[(m, f)],
         in_dtypes=[dtype] * 3,
         out_dtypes=[dtype],
         flops_per_step=4.0 * bm * bn * d,
@@ -179,7 +183,8 @@ def mlp_matmul_stream_pallas(x: jax.Array, w_gate: jax.Array,
                   pl.BlockSpec((d, bn), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, f), x.dtype),
-        compiler_params=tpu_compiler_params(("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, w_gate, w_up)
 
@@ -216,6 +221,8 @@ def _split_analysis(p, *, m: int, d: int, f: int, act: str = "silu",
     return dict(
         in_blocks=[(bm, bk), (bk, bn)],
         out_blocks=[(bm, bn), (bm, bn)],     # f32 pass output + combine
+        in_shapes=[(m, d), (d, f)],
+        out_shapes=[(m, f), (m, f)],
         in_dtypes=[dtype, dtype],
         out_dtypes=["float32", dtype],
         flops_per_step=2.0 * bm * bn * bk,
@@ -253,8 +260,8 @@ def mlp_matmul_split_pallas(x: jax.Array, w_gate: jax.Array,
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
             out_shape=jax.ShapeDtypeStruct((m, f), jnp.float32),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=tpu_compiler_params(
-                ("parallel", "parallel", "arbitrary")),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(x, w)
 

@@ -18,10 +18,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.api import divisors, tuned_kernel
 from repro.kernels.common import (cdiv, default_interpret, require_shape,
-                                  require_tiling, tpu_compiler_params)
+                                  require_tiling)
 from repro.kernels.ref import rms_norm_ref
 
 __all__ = ["rms_norm_pallas"]
@@ -41,6 +42,8 @@ def _rms_analysis(p, *, m: int, d: int, dtype: str = "float32"):
     return dict(
         in_blocks=[(bm, d), (1, d)],
         out_blocks=[(bm, d)],
+        in_shapes=[(m, d), (1, d)],
+        out_shapes=[(m, d)],
         in_dtypes=[dtype, dtype],
         out_dtypes=[dtype],
         flops_per_step=0.0,
@@ -83,6 +86,7 @@ def rms_norm_pallas(x: jax.Array, w: jax.Array, eps: float = 1e-6, *,
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bm, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d), x.dtype),
-        compiler_params=tpu_compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w.reshape(1, d))

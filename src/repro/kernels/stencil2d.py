@@ -26,10 +26,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.api import cuda_profile, divisors, get_spec, tuned_kernel
-from repro.kernels.common import (cdiv, default_interpret, require_tiling,
-                                  tpu_compiler_params)
+from repro.kernels.common import cdiv, default_interpret, require_tiling
 
 __all__ = ["stencil2d_pallas", "stencil2d_ref", "make_tunable_stencil2d"]
 
@@ -81,6 +81,8 @@ def _stencil2d_analysis(p, *, y: int, x: int, dtype: str = "float32"):
     return dict(
         in_blocks=[(by, x)] * 3,
         out_blocks=[(by, x)],
+        in_shapes=[(y, x)] * 3,
+        out_shapes=[(y, x)],
         in_dtypes=[dtype] * 3,
         out_dtypes=[dtype],
         flops_per_step=0.0,
@@ -136,7 +138,8 @@ def stencil2d_pallas(u: jax.Array, *, by: int = 32,
         ],
         out_specs=pl.BlockSpec((by, x), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((y, x), u.dtype),
-        compiler_params=tpu_compiler_params(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(u, u, u)
 

@@ -59,7 +59,7 @@ def _parse_variant(variant: str, cfg):
 
     Components joined by '+': ``sp`` (sequence-parallel residuals),
     ``kvseq`` (split-KV decode cache), ``mb<k>`` (microbatch override),
-    ``padE<n>`` (pad MoE experts to n).  See EXPERIMENTS.md §Perf.
+    ``padE<n>`` (pad MoE experts to n).
     """
     import dataclasses as _dc
     from repro.distributed.sharding import (ACT_RULES, ACT_RULES_SP,

@@ -40,9 +40,11 @@ def main():
     from repro.configs import get_config, get_smoke
     from repro.data import DataConfig, TokenStream
     from repro.distributed import TrainStepConfig, make_train_step
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import build_model
     from repro.optim import AdamWConfig, init_adamw
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     mesh = None

@@ -42,8 +42,8 @@ def init_moe(key, d_model: int, d_ff: int, n_experts: int,
     """``pad_to``: physically allocate max(n_experts, pad_to) experts so
     the expert dim divides the TP axis (e.g. 60 -> 64); the router only
     ever routes to the first n_experts (padding rows are dead weight,
-    ~6% memory for qwen2-moe, bought back many times over in avoided
-    dispatch collectives — see EXPERIMENTS.md §Perf)."""
+    ~6% memory for qwen2-moe, traded for expert shards that need no
+    dispatch collectives to line up with the TP axis)."""
     ks = jax.random.split(key, 5)
     e = max(n_experts, pad_to) if pad_to else n_experts
     d, f = d_model, d_ff
@@ -121,8 +121,8 @@ def moe_layer(p: Dict, x: jax.Array, *, n_experts: int, top_k: int,
     ``dispatch='grouped'``: GShard-style per-sequence groups — routing
     capacity is per group, the scatter is group-local, and the
     (B, E, C, D) buffer is (batch x expert)-sharded with no resharding
-    before the GEMM.  Trades a little capacity headroom for an order of
-    magnitude less dispatch traffic (EXPERIMENTS.md §Perf).
+    before the GEMM.  Trades a little capacity headroom for less
+    dispatch traffic (compare the two with `repro.launch.dryrun`).
     """
     b, s, d = x.shape
     t = b * s

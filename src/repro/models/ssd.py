@@ -116,10 +116,12 @@ def _ssd_chunked(xh, dt, a, b_in, c_in, cfg: SsdConfig):
     seg_total = cum[:, :, -1:, :]                    # (B,NC,1,H)
 
     # ---- intra-chunk (quadratic within chunk) -----------------------------
-    # decay matrix Λ[i,j] = exp(cum_i - cum_j) for i >= j else 0
+    # decay matrix Λ[i,j] = exp(cum_i - cum_j) for i >= j else 0.  Mask
+    # before the exp: above the diagonal cum_i - cum_j > 0 grows with
+    # the chunk and overflows, and exp's gradient there (inf * 0) is NaN.
     diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]   # (B,NC,L,L,H)
     tri = jnp.tril(jnp.ones((l, l), bool))
-    lam = jnp.where(tri[None, None, :, :, None], jnp.exp(diff), 0.0)
+    lam = jnp.exp(jnp.where(tri[None, None, :, :, None], diff, -jnp.inf))
     scores = jnp.einsum("bcin,bcjn->bcij", cc, bc)         # (B,NC,L,L)
     w = scores[..., None] * lam * dtc[:, :, None, :, :]    # (B,NC,L,L,H)
     y_intra = jnp.einsum("bcijh,bcjhp->bcihp", w, xc)

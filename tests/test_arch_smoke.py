@@ -77,3 +77,31 @@ def test_prefill_decode(arch):
     assert logits2.shape == (B, 1, cfg.vocab)
     assert np.isfinite(np.asarray(logits2, np.float32)).all()
     assert int(cache2["pos"]) == int(cache["pos"]) + 1
+
+
+@pytest.mark.parametrize("arch", ["gemma-7b", "hymba-1.5b"])
+def test_decode_past_prompt_matches_longer_prefill(arch):
+    """Serving sizes the cache for prompt + generated tokens: decoding
+    G tokens after a P-token prefill gives the last logits of a prefill
+    of all P + G tokens (dense: linear cache; hybrid: ring cache whose
+    global layers must keep the prompt's head).  The hybrid bound
+    covers the bf16 chunked-scan vs recurrent SSM state update."""
+    from repro.distributed import make_serve_fns
+    p_len, gen = 16, 4
+    cfg = get_smoke(arch)
+    model = build_model(cfg)
+    key = jax.random.PRNGKey(3)
+    params = model.init(key)
+    full = jax.random.randint(key, (B, p_len + gen), 0, cfg.vocab)
+    prefill, decode_step = make_serve_fns(model)
+    want = jax.jit(prefill)(params, {"tokens": full})[0][:, -1]
+    _, cache = jax.jit(lambda p, b: prefill(p, b, max_len=p_len + gen))(
+        params, {"tokens": full[:, :p_len]})
+    decode_step = jax.jit(decode_step)
+    for i in range(gen):
+        logits, cache = decode_step(params, cache,
+                                    full[:, p_len + i:p_len + i + 1])
+    got = np.asarray(logits[:, -1], np.float32)
+    want = np.asarray(want, np.float32)
+    err = np.linalg.norm(got - want) / np.linalg.norm(want)
+    assert err <= 5e-2, (arch, err)

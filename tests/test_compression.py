@@ -40,7 +40,8 @@ _SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from repro.distributed.compression import ef_compress_grads
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     grads = {"w": jnp.full((16, 8), 3.0, jnp.float32)}
     opt = {"count": jnp.zeros((), jnp.int32)}
     with mesh:

@@ -183,10 +183,8 @@ def test_spearman_self_correlation(xs):
 def test_logical_spec_always_valid(dims, shape):
     """Whatever the dims/shape, the resolved spec is consistent: each
     mesh axis used at most once and every sharded dim divisible."""
-    import jax
     if len(dims) != len(shape):
         shape = (shape * 4)[:len(dims)]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         axis_names = ("data", "model")

@@ -24,7 +24,8 @@ _SCRIPT = textwrap.dedent("""
     from repro.models.config import ShapeSpec
     from repro.optim import AdamWConfig
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_smoke("starcoder2-3b")
     model = build_model(cfg)
     shape = ShapeSpec("t", 64, 8, "train")

@@ -22,7 +22,7 @@ from repro.core import KernelTuner
 from repro.core.annotations import annotate_kernel
 from repro.kernels import api, ops
 from repro.kernels.api import divisors, tuned_kernel
-from repro.kernels.common import cdiv
+from repro.kernels.common import cdiv, pick_divisor_candidates, tile_legal
 from repro.tuning_cache import TuningDatabase
 
 
@@ -123,16 +123,43 @@ def test_tuned_params_bypass_and_fallback():
     _declare_toy(kid)
     try:
         spec = api.get_spec(kid)
-        a = jnp.ones((48, 128), jnp.float32)     # 48: candidates (8, 16)
+        # 48: candidates (8, 16) divide it, neither is lane-aligned, so
+        # the full extent joins them: (8, 16, 48)
+        a = jnp.ones((48, 128), jnp.float32)
         np.testing.assert_allclose(spec.op(a, tuned_params={"bm": 8}),
                                    a * 2.0)
-        # derived fallback: the largest dividing candidate
-        assert spec.fallback_params(m=48) == {"bm": 16}
+        # derived fallback: the largest candidate
+        assert spec.fallback_params(m=48) == {"bm": 48}
         assert spec.fallback_params(m=64) == {"bm": 64}
         # no candidate divides -> the dimension itself (never crashes)
         assert spec.fallback_params(m=13) == {"bm": 13}
     finally:
         api.unregister(kid)
+
+
+def test_failed_resolve_counts_as_fallback_and_fails_the_audit(monkeypatch):
+    """A dispatch whose database lookup raises still runs (fallback
+    params), but it is counted as a fallback, so the serving audit
+    `chip_smoke.py` gates on cannot pass it as tuned."""
+    from repro.launch.serve import frozen_audit_problems
+    kid = "toy_resolve_fails"
+    _declare_toy(kid)
+
+    def broken(*a, **k):
+        raise RuntimeError("database unavailable")
+    monkeypatch.setattr(tuning_cache, "lookup_or_tune", broken)
+    api.reset_dispatch_stats()          # the counters are process-global
+    try:
+        a = jnp.ones((64, 128), jnp.float32)
+        np.testing.assert_allclose(api.get_spec(kid).op(a), a * 2.0)
+        st = api.dispatch_stats()
+        assert (st["fallback"], st["total"]) == (1, 1)
+        problems = frozen_audit_problems({"dispatch": st,
+                                          "runtime_tunes": 0})
+        assert any("fallback=1" in p for p in problems), problems
+    finally:
+        api.unregister(kid)
+        api.reset_dispatch_stats()
 
 
 def test_fallback_params_stay_vmem_feasible():
@@ -144,13 +171,60 @@ def test_fallback_params_stay_vmem_feasible():
                      ("atax", dict(m=4096, n=4096)),
                      ("matmul", dict(m=4096, n=4096, k=4096)),
                      ("flash_attention",
-                      dict(b=1, h=8, sq=4096, skv=4096, d=128))]:
+                      dict(b=1, h=8, sq=4096, skv=4096, d=128)),
+                     # d = n = 1600 has no lane-aligned divisor
+                     ("mlp_matmul", dict(m=4096, d=1600, f=5504,
+                                         dtype="bfloat16")),
+                     ("matmul", dict(m=4096, n=1600, k=5504,
+                                     dtype="bfloat16"))]:
         spec = api.get_spec(kid)
         fb = spec.fallback_params(**sig)
         assert spec.static_info(fb, **sig).feasible(), (kid, fb)
     # the old conservative caps are reproduced where VMEM binds
     assert api.get_spec("jacobi3d").fallback_params(
         z=64, y=256, x=256) == {"bz": 8}
+
+
+@pytest.mark.parametrize("block,array,dtype,legal", [
+    ((512, 128), (4096, 5504), "bfloat16", True),
+    ((128, 64), (5504, 1600), "bfloat16", False),   # lane dim 64 of 1600
+    ((128, 1600), (5504, 1600), "bfloat16", True),  # full extent
+    ((8, 1600), (4096, 1600), "float32", True),
+    ((8, 1600), (4096, 1600), "bfloat16", False),   # bf16 rows pack by 16
+    ((4, 1600), (4, 1600), "bfloat16", True),       # full extent rows
+    ((1, 512, 128), (32, 1024, 128), "float32", True),
+])
+def test_tile_legal_is_the_tpu_block_rule(block, array, dtype, legal):
+    assert bool(tile_legal(block, array, dtype)) is legal
+    # the batched form agrees row by row
+    rows = np.array([block[-2]] * 3)
+    assert tile_legal((rows, block[-1]), array, dtype).tolist() == \
+        [legal] * 3
+
+
+def test_full_extent_joins_divisors_without_a_lane_aligned_one():
+    sizes = (8, 16, 32, 64, 128, 256, 512, 1024)
+    assert pick_divisor_candidates(1600, sizes) == (8, 16, 32, 64, 1600)
+    assert pick_divisor_candidates(5504, sizes) == (8, 16, 32, 64, 128)
+    assert pick_divisor_candidates(4096, sizes) == sizes
+    assert pick_divisor_candidates(4, sizes) == (4,)
+
+
+@pytest.mark.parametrize("arch", ["hymba-1.5b", "starcoder2-3b"])
+def test_graph_pretune_picks_obey_the_tpu_block_rule(arch):
+    """Every pick the static model makes for a served config at full
+    width (batch 4, prompt 1024, prefill and decode) is a block the
+    TPU compiler accepts: misaligned tiles are infeasible, not merely
+    slower."""
+    from repro.configs import get_config
+    from repro.core import GraphTuner
+    rep = GraphTuner.tune_config(get_config(arch), batch=4, prompt_len=1024,
+                                 db=TuningDatabase())
+    assert rep["tuned"] == len(rep["instances"]) > 0
+    for inst in rep["instances"]:
+        spec = api.get_spec(inst["kernel"])
+        info = spec.static_info(inst["params"], **inst["signature"])
+        assert info.tiles_legal and info.feasible(), inst
 
 
 def test_unregister_evicts_memoized_ops_attr():

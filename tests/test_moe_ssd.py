@@ -111,6 +111,20 @@ def test_ssd_chunked_matches_recurrence(t, chunk):
                                s_final, rtol=2e-3, atol=2e-3)
 
 
+def test_ssd_gradient_is_finite_at_a_full_size_chunk():
+    """A chunk of 128 (hymba-1.5b's) spans decays whose inverse, above
+    the diagonal of the intra-chunk matrix, overflows f32: training
+    must still see finite gradients."""
+    key = jax.random.PRNGKey(4)
+    cfg = SsdConfig(d_model=16, ssm_state=8, expand=2, head_dim=8,
+                    chunk=128)
+    p = init_ssd(key, cfg)
+    x = jax.random.normal(key, (1, 256, 16), jnp.float32)
+    grads = jax.grad(lambda p: ssd_block(p, x, cfg, SHD).sum())(p)
+    for leaf in jax.tree.leaves(grads):
+        assert np.isfinite(np.asarray(leaf)).all()
+
+
 def test_ssd_prefill_decode_parity():
     """Decode from a prefilled state must equal the full forward."""
     key = jax.random.PRNGKey(3)

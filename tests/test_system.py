@@ -123,7 +123,8 @@ _SPMD_SCRIPT = textwrap.dedent("""
     from repro.models.config import ShapeSpec
     from repro.optim import AdamWConfig
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_smoke("gemma-7b")
     model = build_model(cfg)
     shape = ShapeSpec("tiny_train", 64, 8, "train")
@@ -156,3 +157,25 @@ def test_spmd_submesh_lowering_and_collectives():
     assert rec["collective_bytes"] > 0
     assert "all-reduce" in rec["kinds"] or "all-gather" in rec["kinds"]
     assert rec["flops"] > 0
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir_is_placed_from_outside(tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` places the persistent compile cache;
+    without it the cache lives at one fixed path inside the checkout."""
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(REPO, ".jax_cache")
+    if from_env:
+        want = str(tmp_path / "cc")
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    code = ("import jax\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "path = enable_compile_cache()\n"
+            "print(path, jax.config.jax_compilation_cache_dir,\n"
+            "      jax.config.jax_enable_compilation_cache)\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [want, want, "True"]

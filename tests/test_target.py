@@ -81,6 +81,32 @@ def test_default_target_fallback_is_v5e():
     assert default_target() is TPU_V5E
 
 
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", TPU_V5E),
+    ("tpu", "TPU v5", TPU_V5P),
+    ("tpu", "TPU v99 imaginary", KeyError),
+    ("cpu", "cpu", TPU_V5E),
+])
+def test_detected_target_never_guesses_a_tpu(monkeypatch, platform, kind,
+                                             want):
+    """A TPU backend is analyzed as the chip it reports, or not at all;
+    only a non-TPU backend gets the v5e analysis target."""
+    import jax
+
+    class FakeDevice:
+        pass
+    dev = FakeDevice()
+    dev.platform, dev.device_kind = platform, kind
+    with monkeypatch.context() as m:
+        m.setattr(jax, "devices", lambda *a, **k: [dev])
+        m.setattr(target_mod, "_detected", None)
+        if want is KeyError:
+            with pytest.raises(KeyError, match="unknown hardware target"):
+                default_target()
+        else:
+            assert default_target() is want
+
+
 def test_env_override(monkeypatch):
     monkeypatch.setenv(target_mod.ENV_TARGET, "tpu-v5p")
     assert default_target() is TPU_V5P
