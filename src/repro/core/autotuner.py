@@ -561,41 +561,47 @@ class GraphTuner:
         """
         import jax
         import jax.numpy as jnp
+        from repro import obs
         from repro.distributed import make_serve_fns
         from repro.kernels import api
         from repro.models import build_model
         from repro.models.layers import use_tuned_layers
 
-        model = build_model(cfg)
-        params_abs = model.abstract_params()
-        prefill, decode_step = make_serve_fns(model)
-        batch_abs = {"tokens": jax.ShapeDtypeStruct((batch, prompt_len),
-                                                    jnp.int32)}
-        if cfg.frontend == "frames":
-            batch_abs["frames"] = jax.ShapeDtypeStruct(
-                (batch, cfg.enc_seq, cfg.d_model), jnp.dtype(cfg.dtype))
-        with use_tuned_layers(), api.collect_dispatches() as col:
-            _, cache_abs = jax.eval_shape(prefill, params_abs, batch_abs)
-            if decode:
-                tok = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
-                jax.eval_shape(decode_step, params_abs, cache_abs, tok)
-        # dedup preserving first-seen order (layers repeat instances)
-        seen: Dict[Any, Dict[str, Any]] = {}
-        for kid, sig in col:
-            k = (kid, tuple(sorted(sig.items())))
-            if k not in seen:
-                seen[k] = {"kernel": kid, "signature": sig,
-                           "params": None}
-        report = {"config": cfg.name, "batch": batch,
-                  "prompt_len": prompt_len,
-                  "instances": list(seen.values()),
-                  "dispatches": len(col), "tuned": 0}
-        if tune:
-            from repro.tuning_cache import lookup_or_tune
-            for inst in report["instances"]:
-                kw = {} if db is None else {"db": db}
-                inst["params"] = lookup_or_tune(
-                    inst["kernel"], spec=spec, mode=mode, **kw,
-                    **inst["signature"])
-                report["tuned"] += 1
-        return report
+        with obs.span("pretune", config=cfg.name, batch=batch,
+                      prompt_len=prompt_len):
+            model = build_model(cfg)
+            params_abs = model.abstract_params()
+            prefill, decode_step = make_serve_fns(model)
+            batch_abs = {"tokens": jax.ShapeDtypeStruct((batch, prompt_len),
+                                                        jnp.int32)}
+            if cfg.frontend == "frames":
+                batch_abs["frames"] = jax.ShapeDtypeStruct(
+                    (batch, cfg.enc_seq, cfg.d_model), jnp.dtype(cfg.dtype))
+            with obs.span("pretune.enumerate"), use_tuned_layers(), \
+                    api.collect_dispatches() as col:
+                _, cache_abs = jax.eval_shape(prefill, params_abs, batch_abs)
+                if decode:
+                    tok = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+                    jax.eval_shape(decode_step, params_abs, cache_abs, tok)
+            # dedup preserving first-seen order (layers repeat instances)
+            seen: Dict[Any, Dict[str, Any]] = {}
+            for kid, sig in col:
+                k = (kid, tuple(sorted(sig.items())))
+                if k not in seen:
+                    seen[k] = {"kernel": kid, "signature": sig,
+                               "params": None}
+            report = {"config": cfg.name, "batch": batch,
+                      "prompt_len": prompt_len,
+                      "instances": list(seen.values()),
+                      "dispatches": len(col), "tuned": 0}
+            if tune:
+                from repro.tuning_cache import lookup_or_tune
+                with obs.span("pretune.resolve",
+                              instances=len(report["instances"])):
+                    for inst in report["instances"]:
+                        kw = {} if db is None else {"db": db}
+                        inst["params"] = lookup_or_tune(
+                            inst["kernel"], spec=spec, mode=mode, **kw,
+                            **inst["signature"])
+                        report["tuned"] += 1
+            return report

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from typing import TYPE_CHECKING
 
+from repro import obs
 from repro.distributed.sharding import (ACT_RULES, CACHE_RULES, Rules,
                                         Sharder, WEIGHT_RULES)
 from repro.optim.adamw import (AdamWConfig, adamw_update, global_norm,
@@ -143,10 +144,14 @@ def make_serve_fns(model: "Model", mesh=None,
                   cache_rules=step_cfg.cache_rules,
                   weight_rules=step_cfg.weight_rules)
 
+    # these bodies run only while JAX traces them: the spans time the
+    # Python trace of the model (trace-time dispatch included)
     def prefill(params, batch, max_len: int = 0):
-        return model.prefill(params, batch, shd, max_len=max_len)
+        with obs.span("trace.prefill"):
+            return model.prefill(params, batch, shd, max_len=max_len)
 
     def decode_step(params, cache, token):
-        return model.decode_step(params, cache, token, shd)
+        with obs.span("trace.decode"):
+            return model.decode_step(params, cache, token, shd)
 
     return prefill, decode_step

@@ -55,7 +55,7 @@ from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
 
 import numpy as np
 
-from repro import tuning_cache
+from repro import obs, tuning_cache
 from repro.tuning_cache.binder import SigBinder, compile_binder, schema_of
 from repro.core.annotations import parse_tuning_spec
 from repro.core.autotuner import KernelStaticInfo, TunableKernel
@@ -250,48 +250,27 @@ _GENERIC_CUDA = CudaProfile()
 # ---------------------------------------------------------------------------
 
 
-class _DispatchStats:
-    """Process-wide op-dispatch tier counters.
-
-    Plain uncontended attribute increments: cheap enough for the frozen
-    hot path, and the gates built on them ("100% frozen, zero fallback"
-    after a graph pretune) only ever assert counters that a lost racing
-    increment cannot push from zero to nonzero.
-    """
-
-    __slots__ = ("frozen", "live", "fallback", "explicit", "collected")
-
-    def __init__(self):
-        self.reset()
-
-    def reset(self) -> None:
-        self.frozen = 0         # frozen-table probe answered
-        self.live = 0           # db/memo/service resolve answered in full
-        self.fallback = 0       # largest-divisor fallback filled gaps
-        self.explicit = 0       # caller passed tuned_params=
-        self.collected = 0      # recorded by collect_dispatches()
-
-    def snapshot(self) -> Dict[str, int]:
-        d = {"frozen": self.frozen, "live": self.live,
-             "fallback": self.fallback, "explicit": self.explicit,
-             "collected": self.collected}
-        d["total"] = d["frozen"] + d["live"] + d["fallback"] + d["explicit"]
-        return d
-
-
-_STATS = _DispatchStats()
+# frozen: the frozen-table probe answered; live: the db/memo/service
+# resolve answered in full; fallback: largest-divisor params filled gaps;
+# explicit: the caller passed tuned_params=; collected: recorded by
+# collect_dispatches()
+_TIERS = ("frozen", "live", "fallback", "explicit", "collected")
 
 
 def dispatch_stats() -> Dict[str, int]:
     """Counters of how op dispatches resolved since the last reset:
     ``frozen`` / ``live`` / ``fallback`` / ``explicit`` (+ their sum
     ``total``) and ``collected`` (enumeration-only dispatches recorded
-    under `collect_dispatches`, excluded from ``total``)."""
-    return _STATS.snapshot()
+    under `collect_dispatches`, excluded from ``total``).  A view of the
+    ``dispatch.<tier>`` counters of `repro.obs`."""
+    c = obs.counters()
+    d = {k: c.get(f"dispatch.{k}", 0) for k in _TIERS}
+    d["total"] = d["frozen"] + d["live"] + d["fallback"] + d["explicit"]
+    return d
 
 
 def reset_dispatch_stats() -> None:
-    _STATS.reset()
+    obs.reset_counters("dispatch.")
 
 
 _COLLECT: "contextvars.ContextVar[Optional[List[Tuple[str, Dict]]]]" = \
@@ -807,7 +786,7 @@ class KernelSpec:
         if self._op is None:
             kernel_id = self.kernel_id
             registry = tuning_cache.registry
-            stats = _STATS
+            count = obs.count
             # (frozen state, probe) pair published as ONE tuple: a
             # single attribute store is atomic under the GIL, so racing
             # dispatch threads can never pair a stale probe with a
@@ -821,13 +800,15 @@ class KernelSpec:
                 col = _COLLECT.get()
                 if col is not None:
                     col.append((kernel_id, dict(sig)))
-                    stats.collected += 1
+                    count("dispatch.collected")
                     fn, launch, _ = self._launch(None, sig)
-                    return fn(*args, **launch, **kw)
+                    with obs.scope(kernel_id):
+                        return fn(*args, **launch, **kw)
                 if tuned_params is not None:
-                    stats.explicit += 1
+                    count("dispatch.explicit")
                     fn, launch, _ = self._launch(tuned_params, sig)
-                    return fn(*args, **launch, **kw)
+                    with obs.scope(kernel_id):
+                        return fn(*args, **launch, **kw)
                 fz = registry._FROZEN
                 state, probe = cache[0]
                 if state is not fz:
@@ -844,13 +825,10 @@ class KernelSpec:
                 if p is None:
                     p = _resolve(kernel_id, sig)
                 fn, launch, complete = self._launch(p, sig)
-                if not complete:
-                    stats.fallback += 1
-                elif hit_frozen:
-                    stats.frozen += 1
-                else:
-                    stats.live += 1
-                return fn(*args, **launch, **kw)
+                count("dispatch.fallback" if not complete else
+                      "dispatch.frozen" if hit_frozen else "dispatch.live")
+                with obs.scope(kernel_id):
+                    return fn(*args, **launch, **kw)
 
             op.__name__ = self.kernel_id
             op.__qualname__ = self.kernel_id

@@ -15,12 +15,13 @@ from __future__ import annotations
 import argparse
 import contextlib
 import functools
-import time
 from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import obs
 
 
 def _warm_tuning_db(db, path: str, strict: bool = False):
@@ -142,14 +143,14 @@ def serve(cfg, *, batch: int, prompt_len: int, gen: int, seed: int = 0,
     # Compile both programs apart from any run (set-up time).  Tuned
     # layers resolve their kernels while these trace.
     with routing:
-        t0 = time.perf_counter()
-        prefill_c = prefill.lower(params, batch_in).compile()
-        t_prefill_c = time.perf_counter() - t0
+        with obs.span("serve.compile.prefill") as sp_prefill_c:
+            prefill_c = prefill.lower(params, batch_in).compile()
         _, cache_abs = jax.eval_shape(prefill, params, batch_in)
         tok_abs = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
-        t0 = time.perf_counter()
-        decode_c = decode_step.lower(params, cache_abs, tok_abs).compile()
-        t_decode_c = time.perf_counter() - t0
+        with obs.span("serve.compile.decode") as sp_decode_c:
+            decode_c = decode_step.lower(params, cache_abs, tok_abs).compile()
+    t_prefill_c = obs.seconds(sp_prefill_c)
+    t_decode_c = obs.seconds(sp_decode_c)
     print(f"[serve] compile: prefill {t_prefill_c:.2f} s, "
           f"decode {t_decode_c:.2f} s")
 
@@ -170,21 +171,22 @@ def serve(cfg, *, batch: int, prompt_len: int, gen: int, seed: int = 0,
     jax.block_until_ready((tok, cache))
     del cache
 
-    t0 = time.perf_counter()
-    prefill_logits, cache = prefill_c(params, batch_in)
-    jax.block_until_ready(prefill_logits)
-    t_prefill = time.perf_counter() - t0
+    with obs.span("serve.prefill", batch=batch,
+                  prompt_len=prompt_len) as sp_prefill:
+        prefill_logits, cache = prefill_c(params, batch_in)
+        jax.block_until_ready(prefill_logits)
+    t_prefill = obs.seconds(sp_prefill)
     print(f"[serve] prefill {batch}x{prompt_len}: {t_prefill*1e3:.1f} ms")
 
     tok, key = next_token(prefill_logits, key)
     out_tokens = [tok]
-    t0 = time.perf_counter()
-    for _ in range(gen):
-        logits, cache = decode_c(params, cache, tok)
-        tok, key = next_token(logits, key)
-        out_tokens.append(tok)
-    jax.block_until_ready(tok)
-    dt = (time.perf_counter() - t0) / max(gen, 1)
+    with obs.span("serve.decode", batch=batch, steps=gen) as sp_decode:
+        for _ in range(gen):
+            logits, cache = decode_c(params, cache, tok)
+            tok, key = next_token(logits, key)
+            out_tokens.append(tok)
+        jax.block_until_ready(tok)
+    dt = obs.seconds(sp_decode) / max(gen, 1)
     toks = np.concatenate([np.asarray(t) for t in out_tokens], axis=1)
     print(f"[serve] decode: {dt*1e3:.1f} ms/token "
           f"({batch} sequences x {gen} tokens)")

@@ -26,10 +26,11 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.distributed.sharding import Sharder
 from repro.models.params import Param, param
 
-__all__ = ["rms_norm", "make_rope", "apply_rope", "init_attention",
+__all__ = ["cast", "rms_norm", "make_rope", "apply_rope", "init_attention",
            "attention", "attention_decode", "init_mlp", "mlp",
            "causal_mask_bias", "AttnConfig", "set_tuned_layers",
            "use_tuned_layers", "tuned_layers_enabled"]
@@ -78,6 +79,13 @@ def _ops():
     return ops
 
 
+def cast(w: Param, dtype) -> jax.Array:
+    """A weight in ``dtype``, converted under scope ``cast`` (so a
+    per-call weight conversion is named on the device trace)."""
+    with obs.scope("cast"):
+        return w.value.astype(dtype)
+
+
 # ---------------------------------------------------------------------------
 # norms / rope
 # ---------------------------------------------------------------------------
@@ -89,11 +97,12 @@ def rms_norm(x: jax.Array, w: Param, eps: float = 1e-6) -> jax.Array:
     Tuned route: flatten to (tokens, D) rows and dispatch through the
     ``rms_norm`` registry op — same f32 mean/rsqrt/scale discipline, so
     the two paths agree to float associativity."""
-    if tuned_layers_enabled():
-        d = x.shape[-1]
-        out = _ops().rms_norm(x.reshape(-1, d), w.value, eps=eps)
-        return out.reshape(x.shape)
-    return _rms(x, w.value, eps)
+    with obs.scope("norm"):
+        if tuned_layers_enabled():
+            d = x.shape[-1]
+            out = _ops().rms_norm(x.reshape(-1, d), w.value, eps=eps)
+            return out.reshape(x.shape)
+        return _rms(x, w.value, eps)
 
 
 def _rms(x, w, eps=1e-6):
@@ -164,19 +173,21 @@ def init_attention(key, cfg: AttnConfig) -> Dict:
 
 
 def _project_qkv(p: Dict, x: jax.Array, cfg: AttnConfig, positions):
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].value.astype(x.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].value.astype(x.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].value.astype(x.dtype))
-    if cfg.qkv_bias:
-        q = q + p["bq"].value.astype(x.dtype)
-        k = k + p["bk"].value.astype(x.dtype)
-        v = v + p["bv"].value.astype(x.dtype)
-    if cfg.qk_norm:
-        q = _rms(q, p["q_norm"].value)
-        k = _rms(k, p["k_norm"].value)
-    inv = make_rope(cfg.head_dim, cfg.rope_theta)
-    q = apply_rope(q, positions[:, :, None], inv)
-    k = apply_rope(k, positions[:, :, None], inv)
+    with obs.scope("qkv"):
+        q = jnp.einsum("bsd,dhk->bshk", x, cast(p["wq"], x.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", x, cast(p["wk"], x.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", x, cast(p["wv"], x.dtype))
+        if cfg.qkv_bias:
+            q = q + cast(p["bq"], x.dtype)
+            k = k + cast(p["bk"], x.dtype)
+            v = v + cast(p["bv"], x.dtype)
+        if cfg.qk_norm:
+            q = _rms(q, p["q_norm"].value)
+            k = _rms(k, p["k_norm"].value)
+    with obs.scope("rope"):
+        inv = make_rope(cfg.head_dim, cfg.rope_theta)
+        q = apply_rope(q, positions[:, :, None], inv)
+        k = apply_rope(k, positions[:, :, None], inv)
     return q, k, v
 
 
@@ -286,19 +297,21 @@ def attention(p: Dict, x: jax.Array, cfg: AttnConfig, shd: Sharder,
     k = shd.act(k, ("batch", "seq", "kv_heads", "head_dim"))
     v = shd.act(v, ("batch", "seq", "kv_heads", "head_dim"))
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    if tuned:
-        out = _attention_tuned(q, k, v, cfg.causal)
-    elif not cfg.causal:
-        out = _sdpa(q, k, v, jnp.zeros((), jnp.float32), scale)
-    elif s < cfg.dense_below:
-        bias = causal_mask_bias(positions[0], positions[0], window)
-        out = _sdpa(q, k, v, bias, scale)
-    else:
-        out = _sdpa_chunked(q, k, v, positions, positions, window,
-                            scale, cfg.chunk_q)
-    out = out.astype(x.dtype)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].value.astype(x.dtype))
-    y = shd.act(y, ("batch", "residual_seq", "embed"))
+    with obs.scope("core"):
+        if tuned:
+            out = _attention_tuned(q, k, v, cfg.causal)
+        elif not cfg.causal:
+            out = _sdpa(q, k, v, jnp.zeros((), jnp.float32), scale)
+        elif s < cfg.dense_below:
+            bias = causal_mask_bias(positions[0], positions[0], window)
+            out = _sdpa(q, k, v, bias, scale)
+        else:
+            out = _sdpa_chunked(q, k, v, positions, positions, window,
+                                scale, cfg.chunk_q)
+    with obs.scope("out"):
+        out = out.astype(x.dtype)
+        y = jnp.einsum("bshk,hkd->bsd", out, cast(p["wo"], x.dtype))
+        y = shd.act(y, ("batch", "residual_seq", "embed"))
     if return_kv:
         # the cache copy lives in the decode-cache layout (kv-head /
         # head_dim sharded), not the activation layout; kv_repeat
@@ -328,37 +341,41 @@ def attention_decode(p: Dict, x: jax.Array, cache_k: jax.Array,
     window = cfg.window if window_override is None else window_override
     positions = jnp.full((b, 1), pos, dtype=jnp.int32)
     q, k_new, v_new = _project_qkv(p, x, cfg, positions)
-    if cfg.kv_repeat > 1:
-        k_new = jnp.repeat(k_new, cfg.kv_repeat, axis=2)
-        v_new = jnp.repeat(v_new, cfg.kv_repeat, axis=2)
-    slot = (pos % s_cache) if rolling else pos
-    cache_k = jax.lax.dynamic_update_slice(
-        cache_k, k_new.astype(cache_k.dtype), (0, slot, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(
-        cache_v, v_new.astype(cache_v.dtype), (0, slot, 0, 0))
-    cache_k = shd.cache(cache_k, ("batch", "cache_seq", "kv_heads",
-                                  "head_dim"))
-    cache_v = shd.cache(cache_v, ("batch", "cache_seq", "kv_heads",
-                                  "head_dim"))
-    idx = jnp.arange(s_cache, dtype=jnp.int32)
-    if rolling:
-        # ring buffer: entry i holds absolute position p ≡ i (mod S_c),
-        # valid if it was written (p <= pos) and inside the window.
-        age = (pos - idx) % s_cache
-        span = jnp.minimum(jnp.asarray(window if not isinstance(window, int)
-                                       or window > 0 else s_cache,
-                                       jnp.int32), s_cache)
-        valid = (age < span) & (age <= pos)
-        bias = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)[None, :]
-    else:
-        ok = idx <= pos
-        static = isinstance(window, int)
-        if not static or window > 0:
-            ok &= (pos - idx) < window
-        bias = jnp.where(ok, 0.0, -1e30).astype(jnp.float32)[None, :]
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    out = _sdpa(q, cache_k, cache_v, bias, scale).astype(x.dtype)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].value.astype(x.dtype))
+    with obs.scope("cache_write"):
+        if cfg.kv_repeat > 1:
+            k_new = jnp.repeat(k_new, cfg.kv_repeat, axis=2)
+            v_new = jnp.repeat(v_new, cfg.kv_repeat, axis=2)
+        slot = (pos % s_cache) if rolling else pos
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, k_new.astype(cache_k.dtype), (0, slot, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, v_new.astype(cache_v.dtype), (0, slot, 0, 0))
+        cache_k = shd.cache(cache_k, ("batch", "cache_seq", "kv_heads",
+                                      "head_dim"))
+        cache_v = shd.cache(cache_v, ("batch", "cache_seq", "kv_heads",
+                                      "head_dim"))
+    with obs.scope("core"):
+        idx = jnp.arange(s_cache, dtype=jnp.int32)
+        if rolling:
+            # ring buffer: entry i holds absolute position p ≡ i (mod
+            # S_c), valid if it was written (p <= pos) and inside the
+            # window.
+            age = (pos - idx) % s_cache
+            span = jnp.minimum(jnp.asarray(
+                window if not isinstance(window, int) or window > 0
+                else s_cache, jnp.int32), s_cache)
+            valid = (age < span) & (age <= pos)
+            bias = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)[None, :]
+        else:
+            ok = idx <= pos
+            static = isinstance(window, int)
+            if not static or window > 0:
+                ok &= (pos - idx) < window
+            bias = jnp.where(ok, 0.0, -1e30).astype(jnp.float32)[None, :]
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+        out = _sdpa(q, cache_k, cache_v, bias, scale).astype(x.dtype)
+    with obs.scope("out"):
+        y = jnp.einsum("bshk,hkd->bsd", out, cast(p["wo"], x.dtype))
     return y, (cache_k, cache_v)
 
 
@@ -391,22 +408,20 @@ def mlp(p: Dict, x: jax.Array, act: str, shd: Sharder) -> jax.Array:
         # (variant-arbitrated fused/stream/split schedule), then the
         # down-projection through the tuned matmul.
         x2 = x.reshape(b * s, d)
-        h = _ops().mlp_matmul(x2, p["w_gate"].value.astype(x.dtype),
-                              p["w_up"].value.astype(x.dtype),
+        h = _ops().mlp_matmul(x2, cast(p["w_gate"], x.dtype),
+                              cast(p["w_up"], x.dtype),
                               act.replace("_glu", ""))
         h = shd.act(h.reshape(b, s, -1), ("batch", "seq", "mlp"))
         f = h.shape[-1]
-        y = _ops().matmul(h.reshape(b * s, f),
-                          p["w_down"].value.astype(x.dtype))
+        y = _ops().matmul(h.reshape(b * s, f), cast(p["w_down"], x.dtype))
         return shd.act(y.reshape(b, s, d), ("batch", "residual_seq",
                                             "embed"))
-    up = jnp.einsum("bsd,df->bsf", x, p["w_up"].value.astype(x.dtype))
+    up = jnp.einsum("bsd,df->bsf", x, cast(p["w_up"], x.dtype))
     if "w_gate" in p:
-        gate = jnp.einsum("bsd,df->bsf", x,
-                          p["w_gate"].value.astype(x.dtype))
+        gate = jnp.einsum("bsd,df->bsf", x, cast(p["w_gate"], x.dtype))
         h = a(gate) * up
     else:
         h = a(up)
     h = shd.act(h, ("batch", "seq", "mlp"))
-    y = jnp.einsum("bsf,fd->bsd", h, p["w_down"].value.astype(x.dtype))
+    y = jnp.einsum("bsf,fd->bsd", h, cast(p["w_down"], x.dtype))
     return shd.act(y, ("batch", "residual_seq", "embed"))

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import Sharder
+from repro.models.layers import cast
 from repro.models.params import Param, param
 
 __all__ = ["SsdConfig", "init_ssd", "ssd_block", "ssd_decode",
@@ -71,7 +72,7 @@ def init_ssd(key, cfg: SsdConfig) -> Dict:
 
 def _split_in(p, x, cfg: SsdConfig):
     di, n, h = cfg.d_inner, cfg.ssm_state, cfg.n_heads
-    zxbcdt = jnp.einsum("bsd,de->bse", x, p["w_in"].value.astype(x.dtype))
+    zxbcdt = jnp.einsum("bsd,de->bse", x, cast(p["w_in"], x.dtype))
     z = zxbcdt[..., :di]
     xbc = zxbcdt[..., di:di + cfg.conv_dim]
     dt = zxbcdt[..., di + cfg.conv_dim:]
@@ -168,21 +169,21 @@ def ssd_block(p: Dict, x: jax.Array, cfg: SsdConfig, shd: Sharder,
     bsz, t, _ = x.shape
     di, n, h = cfg.d_inner, cfg.ssm_state, cfg.n_heads
     z, xbc_raw, dt = _split_in(p, x, cfg)
-    xbc = _causal_conv(xbc_raw, p["conv_w"].value.astype(x.dtype),
-                       p["conv_b"].value.astype(x.dtype), cfg.ssm_conv)
+    xbc = _causal_conv(xbc_raw, cast(p["conv_w"], x.dtype),
+                       cast(p["conv_b"], x.dtype), cfg.ssm_conv)
     xin = xbc[..., :di]
     b_in = xbc[..., di:di + n]
     c_in = xbc[..., di + n:]
     xh = xin.reshape(bsz, t, h, cfg.head_dim)
     xh = shd.act(xh, ("batch", "seq", "ssm_inner", None))
-    a = -jnp.exp(p["a_log"].value.astype(jnp.float32))       # (H,)
+    a = -jnp.exp(cast(p["a_log"], jnp.float32))       # (H,)
     dtp = jax.nn.softplus(dt.astype(jnp.float32)
-                          + p["dt_bias"].value.astype(jnp.float32))
+                          + cast(p["dt_bias"], jnp.float32))
     y, (_a_scan, s_scan) = _ssd_chunked(xh, dtp, a, b_in, c_in, cfg)
     y = y + xc_skip(p, xh)
     y = y.reshape(bsz, t, di).astype(x.dtype)
     y = _rms(y * jax.nn.silu(z), p["norm_w"].value)
-    out = jnp.einsum("bse,ed->bsd", y, p["w_out"].value.astype(x.dtype))
+    out = jnp.einsum("bse,ed->bsd", y, cast(p["w_out"], x.dtype))
     out = shd.act(out, ("batch", "residual_seq", "embed"))
     if return_state:
         k = cfg.ssm_conv
@@ -196,8 +197,8 @@ def ssd_block(p: Dict, x: jax.Array, cfg: SsdConfig, shd: Sharder,
 
 
 def xc_skip(p, xh):
-    return xh.astype(jnp.float32) * p["d_skip"].value.astype(
-        jnp.float32)[None, None, :, None]
+    return (xh.astype(jnp.float32)
+            * cast(p["d_skip"], jnp.float32)[None, None, :, None])
 
 
 def init_ssd_state(bsz: int, cfg: SsdConfig, dtype=jnp.float32):
@@ -216,27 +217,27 @@ def ssd_decode(p: Dict, x: jax.Array, state: Dict, cfg: SsdConfig,
     di, n, h, k = cfg.d_inner, cfg.ssm_state, cfg.n_heads, cfg.ssm_conv
     z, xbc, dt = _split_in(p, x, cfg)                       # (B,1,*)
     window = jnp.concatenate([state["conv"], xbc], axis=1)  # (B,k,C)
-    w = p["conv_w"].value.astype(x.dtype)
+    w = cast(p["conv_w"], x.dtype)
     conv_out = jnp.einsum("bkc,kc->bc", window, w) \
-        + p["conv_b"].value.astype(x.dtype)
+        + cast(p["conv_b"], x.dtype)
     conv_out = jax.nn.silu(conv_out)[:, None, :]            # (B,1,C)
     new_conv = window[:, 1:, :]
 
     xin = conv_out[..., :di].reshape(bsz, h, cfg.head_dim)
     b_in = conv_out[..., di:di + n].reshape(bsz, n)
     c_in = conv_out[..., di + n:].reshape(bsz, n)
-    a = -jnp.exp(p["a_log"].value.astype(jnp.float32))
+    a = -jnp.exp(cast(p["a_log"], jnp.float32))
     dtp = jax.nn.softplus(dt[:, 0].astype(jnp.float32)
-                          + p["dt_bias"].value.astype(jnp.float32))  # (B,H)
+                          + cast(p["dt_bias"], jnp.float32))  # (B,H)
     decay = jnp.exp(dtp * a)                                # (B,H)
     s = state["ssm"]                                        # (B,H,N,P)
     upd = jnp.einsum("bn,bh,bhp->bhnp", b_in.astype(jnp.float32), dtp,
                      xin.astype(jnp.float32))
     s_new = s * decay[..., None, None] + upd
     y = jnp.einsum("bn,bhnp->bhp", c_in.astype(jnp.float32), s_new)
-    y = y + xin.astype(jnp.float32) * p["d_skip"].value.astype(
-        jnp.float32)[None, :, None]
+    y = y + (xin.astype(jnp.float32)
+             * cast(p["d_skip"], jnp.float32)[None, :, None])
     y = y.reshape(bsz, 1, di).astype(x.dtype)
     y = _rms(y * jax.nn.silu(z), p["norm_w"].value)
-    out = jnp.einsum("bse,ed->bsd", y, p["w_out"].value.astype(x.dtype))
+    out = jnp.einsum("bse,ed->bsd", y, cast(p["w_out"], x.dtype))
     return out, {"ssm": s_new, "conv": new_conv}

@@ -16,10 +16,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.distributed.sharding import Sharder
 from repro.models.config import ModelConfig
 from repro.models.layers import (AttnConfig, attention, attention_decode,
-                                 init_attention, init_mlp, mlp, rms_norm)
+                                 cast, init_attention, init_mlp, mlp,
+                                 rms_norm)
 from repro.models.moe import init_moe, moe_layer
 from repro.models.params import Param, param, stack_dims
 from repro.models.ssd import (SsdConfig, init_ssd, init_ssd_state,
@@ -150,48 +152,54 @@ def _block_apply(blk: Dict, h: jax.Array, window, cfg: ModelConfig,
     fam = cfg.family
     if fam == "ssm":
         x = rms_norm(h, blk["ln1"])
-        if collect_kv:
-            y, sstate = ssd_block(blk["ssd"], x, ssd_config(cfg), shd,
-                                  return_state=True)
-        else:
-            y = ssd_block(blk["ssd"], x, ssd_config(cfg), shd)
+        with obs.scope("ssm"):
+            if collect_kv:
+                y, sstate = ssd_block(blk["ssd"], x, ssd_config(cfg), shd,
+                                      return_state=True)
+            else:
+                y = ssd_block(blk["ssd"], x, ssd_config(cfg), shd)
         return h + y, aux, (kv, sstate)
     x = rms_norm(h, blk["ln1"])
+    with obs.scope("attn"):
+        if collect_kv:
+            a, kv = attention(blk["attn"], x, acfg, shd,
+                              window_override=window, return_kv=True)
+        else:
+            a = attention(blk["attn"], x, acfg, shd,
+                          window_override=window)
     if fam == "hybrid":
-        from repro.models.layers import _rms
-        if collect_kv:
-            a, kv = attention(blk["attn"], x, acfg, shd,
-                              window_override=window, return_kv=True)
-            m, sstate = ssd_block(blk["ssd"], x, ssd_config(cfg), shd,
-                                  return_state=True)
-        else:
-            a = attention(blk["attn"], x, acfg, shd,
-                          window_override=window)
-            m = ssd_block(blk["ssd"], x, ssd_config(cfg), shd)
-        mix = 0.5 * (_rms(a, blk["norm_a"].value)
-                     * blk["beta_a"].value.astype(h.dtype)
-                     + _rms(m, blk["norm_m"].value)
-                     * blk["beta_m"].value.astype(h.dtype))
-        h = h + mix
+        with obs.scope("ssm"):
+            if collect_kv:
+                m, sstate = ssd_block(blk["ssd"], x, ssd_config(cfg), shd,
+                                      return_state=True)
+            else:
+                m = ssd_block(blk["ssd"], x, ssd_config(cfg), shd)
+        h = h + _mix(blk, a, m, h.dtype)
     else:
-        if collect_kv:
-            a, kv = attention(blk["attn"], x, acfg, shd,
-                              window_override=window, return_kv=True)
-        else:
-            a = attention(blk["attn"], x, acfg, shd,
-                          window_override=window)
         h = h + a
     x2 = rms_norm(h, blk["ln2"])
-    if moe:
-        y, aux = moe_layer(blk["moe"], x2, n_experts=cfg.n_experts,
-                           top_k=cfg.top_k,
-                           capacity_factor=cfg.capacity_factor,
-                           act=cfg.act, shd=shd,
-                           pad_to=cfg.pad_experts_to,
-                           dispatch=cfg.moe_dispatch)
-    else:
-        y = mlp(blk["mlp"], x2, cfg.act, shd)
+    with obs.scope("mlp"):
+        if moe:
+            y, aux = moe_layer(blk["moe"], x2, n_experts=cfg.n_experts,
+                               top_k=cfg.top_k,
+                               capacity_factor=cfg.capacity_factor,
+                               act=cfg.act, shd=shd,
+                               pad_to=cfg.pad_experts_to,
+                               dispatch=cfg.moe_dispatch)
+        else:
+            y = mlp(blk["mlp"], x2, cfg.act, shd)
     return h + y, aux, (kv, sstate)
+
+
+def _mix(blk: Dict, a: jax.Array, m: jax.Array, dtype) -> jax.Array:
+    """Hybrid layer: the normed, scaled mean of the attention and SSM
+    heads' outputs."""
+    from repro.models.layers import _rms
+    with obs.scope("mix"):
+        return 0.5 * (_rms(a, blk["norm_a"].value)
+                      * cast(blk["beta_a"], dtype)
+                      + _rms(m, blk["norm_m"].value)
+                      * cast(blk["beta_m"], dtype))
 
 
 def _scan_blocks(blocks, h, windows, cfg: ModelConfig, shd: Sharder,
@@ -201,8 +209,9 @@ def _scan_blocks(blocks, h, windows, cfg: ModelConfig, shd: Sharder,
     def body(carry, xs):
         hh, aux = carry
         blk, win = xs
-        hh, aux_l, ys = _block_apply(blk, hh, win, cfg, shd, moe,
-                                     collect_kv)
+        with obs.scope("layer"):
+            hh, aux_l, ys = _block_apply(blk, hh, win, cfg, shd, moe,
+                                         collect_kv)
         return (hh, aux + aux_l), ys
 
     if cfg.remat == "full":
@@ -220,8 +229,16 @@ def _scan_blocks(blocks, h, windows, cfg: ModelConfig, shd: Sharder,
 
 def _embed(params, tokens, cfg: ModelConfig, shd: Sharder,
            dtype) -> jax.Array:
-    h = params["embed"].value.astype(dtype)[tokens]
-    return shd.act(h, ("batch", "residual_seq", "embed"))
+    with obs.scope("embed"):
+        h = cast(params["embed"], dtype)[tokens]
+        return shd.act(h, ("batch", "residual_seq", "embed"))
+
+
+def _head(params, h: jax.Array) -> jax.Array:
+    """Final norm, then the logits over the vocabulary."""
+    h = rms_norm(h, params["final_norm"])
+    with obs.scope("head"):
+        return jnp.einsum("bsd,dv->bsv", h, cast(params["lm_head"], h.dtype))
 
 
 def lm_logits(params: Dict, tokens: jax.Array, cfg: ModelConfig,
@@ -250,10 +267,7 @@ def lm_logits(params: Dict, tokens: jax.Array, cfg: ModelConfig,
                                moe=(cfg.family == "moe"),
                                collect_kv=collect_kv)
     aux_total += aux
-    h = rms_norm(h, params["final_norm"])
-    logits = jnp.einsum("bsd,dv->bsv", h,
-                        params["lm_head"].value.astype(h.dtype))
-    logits = shd.act(logits, ("batch", "seq", "vocab"))
+    logits = shd.act(_head(params, h), ("batch", "seq", "vocab"))
     if collect_kv:
         return logits, aux_total, (kv_pre, kvs)
     return logits, aux_total
@@ -319,41 +333,45 @@ def _block_decode(blk, h, win, ck, cv, sstate, pos, cfg: ModelConfig,
     fam = cfg.family
     if fam == "ssm":
         x = rms_norm(h, blk["ln1"])
-        y, sstate = ssd_decode(blk["ssd"], x, sstate, ssd_config(cfg), shd)
+        with obs.scope("ssm"):
+            y, sstate = ssd_decode(blk["ssd"], x, sstate, ssd_config(cfg),
+                                   shd)
         return h + y, (ck, cv, sstate)
     x = rms_norm(h, blk["ln1"])
-    rolling = (fam == "hybrid")
-    if fam == "hybrid":
-        from repro.models.layers import _rms
+    with obs.scope("attn"):
         a, (ck, cv) = attention_decode(blk["attn"], x, ck, cv, pos, acfg,
                                        shd, window_override=win,
-                                       rolling=rolling)
-        m, sstate = ssd_decode(blk["ssd"], x, sstate, ssd_config(cfg), shd)
-        mix = 0.5 * (_rms(a, blk["norm_a"].value)
-                     * blk["beta_a"].value.astype(h.dtype)
-                     + _rms(m, blk["norm_m"].value)
-                     * blk["beta_m"].value.astype(h.dtype))
-        h = h + mix
+                                       rolling=(fam == "hybrid"))
+    if fam == "hybrid":
+        with obs.scope("ssm"):
+            m, sstate = ssd_decode(blk["ssd"], x, sstate, ssd_config(cfg),
+                                   shd)
+        h = h + _mix(blk, a, m, h.dtype)
     else:
-        a, (ck, cv) = attention_decode(blk["attn"], x, ck, cv, pos, acfg,
-                                       shd, window_override=win)
         h = h + a
     x2 = rms_norm(h, blk["ln2"])
-    if moe:
-        y, _ = moe_layer(blk["moe"], x2, n_experts=cfg.n_experts,
-                         top_k=cfg.top_k,
-                         capacity_factor=cfg.capacity_factor,
-                         act=cfg.act, shd=shd,
-                         pad_to=cfg.pad_experts_to,
-                         dispatch=cfg.moe_dispatch)
-    else:
-        y = mlp(blk["mlp"], x2, cfg.act, shd)
+    with obs.scope("mlp"):
+        if moe:
+            y, _ = moe_layer(blk["moe"], x2, n_experts=cfg.n_experts,
+                             top_k=cfg.top_k,
+                             capacity_factor=cfg.capacity_factor,
+                             act=cfg.act, shd=shd,
+                             pad_to=cfg.pad_experts_to,
+                             dispatch=cfg.moe_dispatch)
+        else:
+            y = mlp(blk["mlp"], x2, cfg.act, shd)
     return h + y, (ck, cv, sstate)
 
 
 def lm_decode_step(params: Dict, cache: Dict, token: jax.Array,
                    cfg: ModelConfig, shd: Sharder):
     """One decode step.  token: (B, 1) int32 -> (logits (B, 1, V), cache)."""
+    with obs.scope("decode"):
+        return _decode_step(params, cache, token, cfg, shd)
+
+
+def _decode_step(params: Dict, cache: Dict, token: jax.Array,
+                 cfg: ModelConfig, shd: Sharder):
     dtype = jnp.dtype(cfg.dtype)
     pos = cache["pos"]
     h = _embed(params, token, cfg, shd, dtype)
@@ -367,8 +385,9 @@ def lm_decode_step(params: Dict, cache: Dict, token: jax.Array,
         def body(hh, xs):
             blk, win, ck, cv, s_ssm, s_conv = xs
             sstate = {"ssm": s_ssm, "conv": s_conv} if has_ssm else None
-            hh, (ck, cv, sstate) = _block_decode(
-                blk, hh, win, ck, cv, sstate, pos, cfg, shd, moe)
+            with obs.scope("layer"):
+                hh, (ck, cv, sstate) = _block_decode(
+                    blk, hh, win, ck, cv, sstate, pos, cfg, shd, moe)
             ys = (ck if has_attn else 0,
                   cv if has_attn else 0,
                   sstate["ssm"] if has_ssm else 0,
@@ -403,11 +422,7 @@ def lm_decode_step(params: Dict, cache: Dict, token: jax.Array,
     if "ssm" in cache:
         new_cache["ssm"], new_cache["conv"] = ys[2], ys[3]
     new_cache["pos"] = pos + 1
-
-    h = rms_norm(h, params["final_norm"])
-    logits = jnp.einsum("bsd,dv->bsv", h,
-                        params["lm_head"].value.astype(h.dtype))
-    return logits, new_cache
+    return _head(params, h), new_cache
 
 
 def lm_prefill(params: Dict, tokens: jax.Array, cfg: ModelConfig,
@@ -418,12 +433,23 @@ def lm_prefill(params: Dict, tokens: jax.Array, cfg: ModelConfig,
 
     ``max_len`` sizes the cache for subsequent decode steps (default:
     exactly the prompt length — the dry-run decode-shape convention)."""
-    b, s = (tokens.shape if inputs_embeds is None
-            else inputs_embeds.shape[:2])
-    logits, _aux, (pre_ys, main_ys) = lm_logits(
-        params, tokens, cfg, shd, collect_kv=True,
-        inputs_embeds=inputs_embeds)
-    cache = init_lm_cache(cfg, b, max(s, max_len or 0))
+    with obs.scope("prefill"):
+        b, s = (tokens.shape if inputs_embeds is None
+                else inputs_embeds.shape[:2])
+        logits, _aux, (pre_ys, main_ys) = lm_logits(
+            params, tokens, cfg, shd, collect_kv=True,
+            inputs_embeds=inputs_embeds)
+        with obs.scope("kv_fill"):
+            cache = _fill_cache(cfg, b, s, max(s, max_len or 0), pre_ys,
+                                main_ys)
+        return logits, cache
+
+
+def _fill_cache(cfg: ModelConfig, b: int, s: int, max_len: int, pre_ys,
+                main_ys) -> Dict:
+    """The decode cache, sized for ``max_len``, holding the KV and SSM
+    states that a prefill of ``s`` positions collected."""
+    cache = init_lm_cache(cfg, b, max_len)
 
     def fill_kv(kvs, kname, vname):
         k, v = kvs  # (L, B, S, KV, hd)
@@ -459,4 +485,4 @@ def lm_prefill(params: Dict, tokens: jax.Array, cfg: ModelConfig,
             cache["k_pre"] = kp.astype(cache["k_pre"].dtype)
             cache["v_pre"] = vp.astype(cache["v_pre"].dtype)
     cache["pos"] = jnp.asarray(s, jnp.int32)
-    return logits, cache
+    return cache

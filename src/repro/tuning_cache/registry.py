@@ -785,6 +785,14 @@ def freeze() -> int:
     after re-warming.  Mutating ``REPRO_TUNING_TARGET`` directly after a
     freeze is the one unsupported path — call :func:`thaw` yourself.
     """
+    from repro import obs
+    with obs.span("freeze") as sp:
+        n = _freeze()
+        sp["attrs"]["entries"] = n
+    return n
+
+
+def _freeze() -> int:
     global _FROZEN
     from repro.tuning_cache import get_default_db
     db = get_default_db()

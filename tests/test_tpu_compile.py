@@ -143,3 +143,66 @@ def test_tuned_prefill_compiles_for_one_v5e(one_chip, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < TPU_V5E.hbm_bytes, used
+
+
+@pytest.mark.parametrize("arch", ["hymba-1.5b", "starcoder2-3b"])
+def test_scopes_leave_the_v5e_program_unchanged(one_chip, monkeypatch, arch):
+    """The tuned smoke-size serving programs, compiled for one v5e with
+    the named scopes live and turned off, are the same program up to
+    metadata and instruction numbering, and each Pallas kernel keeps the
+    ``<function>_pallas.N`` instruction name a device trace is read by."""
+    import contextlib
+    import functools
+    import re
+    from repro import obs, tuning_cache
+    from repro.configs import get_smoke
+    from repro.distributed import make_serve_fns
+    from repro.models import build_model
+    from repro.models.layers import use_tuned_layers
+
+    cfg = get_smoke(arch)
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    model = build_model(cfg)
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((2, 128),
+                                                    jax.numpy.int32)})
+
+    def compiled():
+        prefill, _ = make_serve_fns(model)
+        step = jax.jit(functools.partial(prefill, max_len=136))
+        with use_tuned_layers():
+            return step.lower(params, batch).compile().as_text()
+
+    def program(text):
+        text = re.sub(r",? metadata=\{[^}]*\}", "",
+                      text.split("\n\nFileNames")[0])
+        names = {}
+        return re.sub(r"%[A-Za-z0-9_.\-]+", lambda m: names.setdefault(
+            m.group(0), f"%v{len(names)}"), text)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    db = TuningDatabase()
+    tuning_cache.thaw()
+    tuning_cache.set_default_db(db)
+    try:
+        GraphTuner.tune_config(cfg, batch=2, prompt_len=128, db=db)
+        tuning_cache.freeze()
+        live = compiled()
+        with monkeypatch.context() as m:
+            m.setattr(obs, "scope", lambda name: contextlib.nullcontext())
+            jax.clear_caches()
+            off = compiled()
+    finally:
+        tuning_cache.thaw()
+        tuning_cache.reset_default_db()
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert program(live) == program(off)
+    kernels = set(re.findall(r"%([A-Za-z0-9_]+_pallas)\.\d+ = .*custom-call",
+                             live))
+    assert kernels, "no Pallas kernel in the program"
+    assert kernels == set(re.findall(
+        r"%([A-Za-z0-9_]+_pallas)\.\d+ = .*custom-call", off))
