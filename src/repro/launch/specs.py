@@ -29,15 +29,18 @@ def _sds(shape, dtype, sharding=None):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def sharded_params(model: Model, mesh: Mesh,
-                   rules: Rules = WEIGHT_RULES):
-    aparams = model.abstract_params()
-
+def _shard_params(tree, mesh: Mesh, rules: Rules):
+    """A Param tree of abstract values, each sharded by its dims."""
     def attach(p: Param):
         s = named_sharding(p.dims, p.value.shape, rules, mesh)
         return Param(_sds(p.value.shape, p.value.dtype, s), p.dims)
 
-    return map_params(attach, aparams)
+    return map_params(attach, tree)
+
+
+def sharded_params(model: Model, mesh: Mesh,
+                   rules: Rules = WEIGHT_RULES):
+    return _shard_params(model.abstract_params(), mesh, rules)
 
 
 def sharded_opt_state(params_sds, mesh: Mesh):
@@ -82,10 +85,16 @@ _CACHE_DIMS = {
 
 
 def sharded_cache(model: Model, shape: ShapeSpec, mesh: Mesh,
-                  rules: Rules = CACHE_RULES) -> Dict:
+                  rules: Rules = CACHE_RULES,
+                  weight_rules: Rules = WEIGHT_RULES) -> Dict:
+    """The decode state; the layer weights it carries are sharded as
+    the weights they copy."""
     acache = model.abstract_cache(shape.global_batch, shape.seq_len)
     out = {}
     for name, sds in acache.items():
+        if name == "weights":
+            out[name] = _shard_params(sds, mesh, weight_rules)
+            continue
         dims = _CACHE_DIMS.get(name, tuple([None] * len(sds.shape)))
         s = named_sharding(dims, sds.shape, rules, mesh)
         out[name] = _sds(sds.shape, sds.dtype, s)
@@ -109,7 +118,7 @@ def cell_inputs(model: Model, shape: ShapeSpec, mesh: Mesh,
         batch = sharded_batch(model.cfg, shape, mesh, act_rules)
         return (params, batch)
     if shape.kind == "decode":
-        cache = sharded_cache(model, shape, mesh, cache_rules)
+        cache = sharded_cache(model, shape, mesh, cache_rules, weight_rules)
         batch = sharded_batch(model.cfg, shape, mesh, act_rules)
         return (params, cache, batch["token"])
     raise ValueError(shape.kind)

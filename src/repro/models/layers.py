@@ -30,9 +30,9 @@ from repro import obs
 from repro.distributed.sharding import Sharder
 from repro.models.params import Param, param
 
-__all__ = ["cast", "rms_norm", "make_rope", "apply_rope", "init_attention",
-           "attention", "attention_decode", "init_mlp", "mlp",
-           "causal_mask_bias", "AttnConfig", "set_tuned_layers",
+__all__ = ["cast", "count_cast_bytes", "FLOAT32_READS", "rms_norm",
+           "make_rope", "apply_rope", "init_attention", "attention",
+           "attention_decode", "init_mlp", "mlp", "causal_mask_bias", "AttnConfig", "set_tuned_layers",
            "use_tuned_layers", "tuned_layers_enabled"]
 
 
@@ -79,11 +79,48 @@ def _ops():
     return ops
 
 
+# Weight leaves the layers read at float32 whatever the compute dtype:
+# norm scales (through `.value`), the SSD's per-head scalars (cast to
+# float32) and the MoE router.  Every other leaf is read through
+# `cast(w, compute dtype)`.
+FLOAT32_READS = frozenset({"ln1", "ln2", "norm_a", "norm_m", "q_norm",
+                           "k_norm", "norm_w", "a_log", "dt_bias", "d_skip",
+                           "router"})
+
+_CAST_COUNT: "ContextVar[Optional[Tuple[str, int]]]" = ContextVar(
+    "repro_cast_count", default=None)
+
+
+@contextlib.contextmanager
+def count_cast_bytes(counter: Optional[str] = None, times: int = 1):
+    """While a program is traced inside, `cast` adds to the `repro.obs`
+    counter ``counter`` the stored bytes of each weight it converts.  A
+    layer scan traces its body once for all its trips: it passes its
+    length as ``times`` and no counter, which scales an enclosing count."""
+    outer = _CAST_COUNT.get()
+    if counter is None:
+        inner = None if outer is None else (outer[0], outer[1] * times)
+    else:
+        inner = (counter, times)
+    tok = _CAST_COUNT.set(inner)
+    try:
+        yield
+    finally:
+        _CAST_COUNT.reset(tok)
+
+
 def cast(w: Param, dtype) -> jax.Array:
     """A weight in ``dtype``, converted under scope ``cast`` (so a
-    per-call weight conversion is named on the device trace)."""
+    per-call weight conversion is named on the device trace); the
+    stored array itself when it is in ``dtype`` already."""
+    v = w.value
+    if v.dtype == jnp.dtype(dtype):
+        return v
+    counting = _CAST_COUNT.get()
+    if counting is not None:
+        obs.count(counting[0], counting[1] * v.size * v.dtype.itemsize)
     with obs.scope("cast"):
-        return w.value.astype(dtype)
+        return v.astype(dtype)
 
 
 # ---------------------------------------------------------------------------

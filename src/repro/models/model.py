@@ -78,13 +78,22 @@ class Model:
                                          shd)
         return tf.lm_decode_step(params, cache, token, self.cfg, shd)
 
-    def init_cache(self, batch: int, seq_len: int) -> Dict:
+    def init_cache(self, batch: int, seq_len: int,
+                   params: Optional[Dict] = None) -> Dict:
+        """An empty decode state; given ``params``, with what prefill
+        derives from them (the compute-precision layer weights)."""
         if self.cfg.family == "encdec":
             return ed.init_encdec_cache(self.cfg, batch, seq_len)
-        return tf.init_lm_cache(self.cfg, batch, seq_len)
+        return tf.init_lm_cache(self.cfg, batch, seq_len, params=params)
 
-    def abstract_cache(self, batch: int, seq_len: int) -> Dict:
-        return jax.eval_shape(lambda: self.init_cache(batch, seq_len))
+    def abstract_cache(self, batch: int, seq_len: int,
+                       params: Optional[Dict] = None) -> Dict:
+        """The decode state's shapes for ``params`` (by default
+        `abstract_params`), as prefill returns it."""
+        if params is None:
+            params = self.abstract_params()
+        return jax.eval_shape(
+            lambda p: self.init_cache(batch, seq_len, p), params)
 
     # -- accounting -----------------------------------------------------------
     def model_flops(self, shape: ShapeSpec) -> float:

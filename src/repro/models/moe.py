@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import Sharder
+from repro.models.layers import cast
 from repro.models.params import Param, param
 
 __all__ = ["init_moe", "moe_layer", "moe_capacity"]
@@ -141,9 +142,9 @@ def moe_layer(p: Dict, x: jax.Array, *, n_experts: int, top_k: int,
         1.0 / (t * top_k))
     aux = e * jnp.sum(me * ce)
 
-    wg = p["w_gate"].value.astype(x.dtype)
-    wu = p["w_up"].value.astype(x.dtype)
-    wd = p["w_down"].value.astype(x.dtype)
+    wg = cast(p["w_gate"], x.dtype)
+    wu = cast(p["w_up"], x.dtype)
+    wd = cast(p["w_down"], x.dtype)
 
     if dispatch == "grouped":
         from repro.models.layers import _ACTS

@@ -19,16 +19,16 @@ import numpy as np
 from repro import obs
 from repro.distributed.sharding import Sharder
 from repro.models.config import ModelConfig
-from repro.models.layers import (AttnConfig, attention, attention_decode,
-                                 cast, init_attention, init_mlp, mlp,
-                                 rms_norm)
+from repro.models.layers import (FLOAT32_READS, AttnConfig, attention,
+                                 attention_decode, cast, count_cast_bytes,
+                                 init_attention, init_mlp, mlp, rms_norm)
 from repro.models.moe import init_moe, moe_layer
-from repro.models.params import Param, param, stack_dims
+from repro.models.params import Param, param, stack_dims, tree_param_bytes
 from repro.models.ssd import (SsdConfig, init_ssd, init_ssd_state,
                               ssd_block, ssd_decode)
 
 __all__ = ["attn_config", "ssd_config", "init_lm", "lm_logits", "lm_loss",
-           "lm_prefill", "lm_decode_step", "init_lm_cache",
+           "lm_prefill", "lm_decode_step", "init_lm_cache", "decode_weights",
            "hybrid_windows"]
 
 
@@ -227,10 +227,18 @@ def _scan_blocks(blocks, h, windows, cfg: ModelConfig, shd: Sharder,
     return h, aux, kvs
 
 
-def _embed(params, tokens, cfg: ModelConfig, shd: Sharder,
-           dtype) -> jax.Array:
+def _embed(params, tokens, cfg: ModelConfig, shd: Sharder, dtype,
+           rows_first: bool = False) -> jax.Array:
+    """The tokens' embedding rows in ``dtype``.  ``rows_first`` gathers
+    the rows before converting them (the same numbers), so a decode step
+    converts a few rows and not the whole table."""
     with obs.scope("embed"):
-        h = cast(params["embed"], dtype)[tokens]
+        if rows_first:
+            h = params["embed"].value[tokens]
+            with obs.scope("cast"):
+                h = h.astype(dtype)
+        else:
+            h = cast(params["embed"], dtype)[tokens]
         return shd.act(h, ("batch", "residual_seq", "embed"))
 
 
@@ -301,9 +309,56 @@ def _cache_len(cfg: ModelConfig, seq_len: int) -> int:
     return seq_len
 
 
+def _narrowed_leaves(params: Dict, dtype):
+    """(path, Param) of each layer-weight leaf that the layers read
+    through `cast` to ``dtype`` and that is stored wider than it."""
+    for name in ("prefix_blocks", "blocks"):
+        flat, _ = jax.tree_util.tree_flatten_with_path(
+            params.get(name, {}), is_leaf=lambda x: isinstance(x, Param))
+        for path, p in flat:
+            key = path[-1].key
+            if key not in FLOAT32_READS and \
+                    p.value.dtype.itemsize > dtype.itemsize:
+                yield (name,) + tuple(k.key for k in path), p
+
+
+def decode_weights(params: Dict, cfg: ModelConfig) -> Dict:
+    """The stacked layer weights that the layers read in ``cfg.dtype``,
+    converted once: the leaves of ``params["blocks"]`` (and
+    ``"prefix_blocks"``) stored wider than ``cfg.dtype``, under the same
+    paths and dims; empty when no leaf is.  Prefill hands it to the
+    decode state, and each decode step reads it in place of converting
+    the stored stack again."""
+    dtype = jnp.dtype(cfg.dtype)
+    out: Dict = {}
+    for path, p in _narrowed_leaves(params, dtype):
+        node = out
+        for k in path[:-1]:
+            node = node.setdefault(k, {})
+        node[path[-1]] = Param(cast(p, dtype), p.dims)
+    return out
+
+
+def _carried(params: Dict, cfg: ModelConfig) -> Dict:
+    """The decode state's entry for `decode_weights`, if it has one."""
+    weights = decode_weights(params, cfg)
+    return {"weights": weights} if weights else {}
+
+
+def _overlay(stored, carried):
+    """``stored`` with each leaf that ``carried`` holds in its place."""
+    if carried is None:
+        return stored
+    if isinstance(carried, Param):
+        return carried
+    return {k: _overlay(v, carried.get(k)) for k, v in stored.items()}
+
+
 def init_lm_cache(cfg: ModelConfig, batch: int, seq_len: int,
-                  dtype=None) -> Dict:
-    """Decode cache: ring/linear KV per attention layer + SSM states."""
+                  dtype=None, params: Optional[Dict] = None) -> Dict:
+    """Decode cache: ring/linear KV per attention layer + SSM states;
+    given the ``params`` it serves, also the compute-precision layer
+    weights that prefill hands over (`decode_weights`)."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     n_main = (cfg.n_layers - cfg.first_dense_layers
               if cfg.family == "moe" else cfg.n_layers)
@@ -324,6 +379,8 @@ def init_lm_cache(cfg: ModelConfig, batch: int, seq_len: int,
         n_l = cfg.n_layers
         cache["ssm"] = jnp.tile(st["ssm"][None], (n_l, 1, 1, 1, 1))
         cache["conv"] = jnp.tile(st["conv"][None], (n_l, 1, 1, 1))
+    if params is not None:
+        cache.update(_carried(params, cfg))
     return cache
 
 
@@ -365,16 +422,28 @@ def _block_decode(blk, h, win, ck, cv, sstate, pos, cfg: ModelConfig,
 
 def lm_decode_step(params: Dict, cache: Dict, token: jax.Array,
                    cfg: ModelConfig, shd: Sharder):
-    """One decode step.  token: (B, 1) int32 -> (logits (B, 1, V), cache)."""
-    with obs.scope("decode"):
+    """One decode step.  token: (B, 1) int32 -> (logits (B, 1, V), cache).
+
+    The layers read the compute-precision weights that the cache
+    carries (``cache["weights"]``, from `lm_prefill`) where the stored
+    ones are wider; a cache without them is refused."""
+    with obs.scope("decode"), \
+            count_cast_bytes("serve.decode_weight_cast_bytes"):
         return _decode_step(params, cache, token, cfg, shd)
 
 
 def _decode_step(params: Dict, cache: Dict, token: jax.Array,
                  cfg: ModelConfig, shd: Sharder):
     dtype = jnp.dtype(cfg.dtype)
+    carried = cache.get("weights", {})
+    if not carried and next(_narrowed_leaves(params, dtype), None):
+        raise ValueError(
+            f"{cfg.name}: the layer weights are stored wider than "
+            f"{dtype.name} but the decode state carries no {dtype.name} "
+            "copy of them; build it with lm_prefill, or init_lm_cache "
+            "given the params")
     pos = cache["pos"]
-    h = _embed(params, token, cfg, shd, dtype)
+    h = _embed(params, token, cfg, shd, dtype, rows_first=True)
     new_cache = dict(cache)
     fam = cfg.family
 
@@ -399,7 +468,8 @@ def _decode_step(params: Dict, cache: Dict, token: jax.Array,
         xs = (blocks, windows,
               k if has_attn else zeros, v if has_attn else zeros,
               ssm if has_ssm else zeros, conv if has_ssm else zeros)
-        h, ys = jax.lax.scan(body, h, xs)
+        with count_cast_bytes(times=n):
+            h, ys = jax.lax.scan(body, h, xs)
         return h, ys
 
     n_main = (cfg.n_layers - cfg.first_dense_layers
@@ -407,13 +477,15 @@ def _decode_step(params: Dict, cache: Dict, token: jax.Array,
     sc = cache["k"].shape[2] if "k" in cache else 0
     if "prefix_blocks" in params:
         npre = cfg.first_dense_layers
-        h, ys = scan_stack(params["prefix_blocks"], h,
+        h, ys = scan_stack(_overlay(params["prefix_blocks"],
+                                    carried.get("prefix_blocks")), h,
                            jnp.full((npre,), max(sc, 1), jnp.int32),
                            cache["k_pre"], cache["v_pre"], None, None,
                            moe=False)
         new_cache["k_pre"], new_cache["v_pre"] = ys[0], ys[1]
     windows = hybrid_windows(cfg, max(sc, 1), n_main)
-    h, ys = scan_stack(params["blocks"], h, windows,
+    h, ys = scan_stack(_overlay(params["blocks"], carried.get("blocks")),
+                       h, windows,
                        cache.get("k"), cache.get("v"),
                        cache.get("ssm"), cache.get("conv"),
                        moe=(fam == "moe"))
@@ -429,7 +501,9 @@ def lm_prefill(params: Dict, tokens: jax.Array, cfg: ModelConfig,
                shd: Sharder, max_len: Optional[int] = None,
                inputs_embeds: Optional[jax.Array] = None):
     """Prefill: full forward collecting per-layer KV + SSM states ->
-    (logits, cache) ready for ``lm_decode_step`` at position s.
+    (logits, cache) ready for ``lm_decode_step`` at position s.  The
+    cache also carries the compute-precision layer weights
+    (`decode_weights`) where the stored ones are wider.
 
     ``max_len`` sizes the cache for subsequent decode steps (default:
     exactly the prompt length — the dry-run decode-shape convention)."""
@@ -442,6 +516,12 @@ def lm_prefill(params: Dict, tokens: jax.Array, cfg: ModelConfig,
         with obs.scope("kv_fill"):
             cache = _fill_cache(cfg, b, s, max(s, max_len or 0), pre_ys,
                                 main_ys)
+        with obs.scope("weights"):
+            carried = _carried(params, cfg)
+        if carried:
+            obs.count("serve.carried_weight_bytes",
+                      tree_param_bytes(carried))
+        cache.update(carried)
         return logits, cache
 
 

@@ -88,11 +88,19 @@ def test_kernels_are_scoped_by_kernel_id():
 
 
 def test_weight_casts_are_named_under_their_layer():
-    _, ld = lowered("hymba-1.5b")
-    casts = set()
-    for path in op_paths(ld):
-        parts = path.split("/")
-        if "layer" in parts and "cast" in parts:
-            casts.add("/".join(parts[parts.index("layer") + 1:
-                                     parts.index("cast")]))
+    """Prefill converts each layer's float32 weights under the layer's
+    scope; the decode step reads the bf16 copy that prefill hands over
+    and converts none of them."""
+    def layer_casts(low):
+        casts = set()
+        for path in op_paths(low):
+            parts = path.split("/")
+            if "layer" in parts and "cast" in parts:
+                casts.add("/".join(parts[parts.index("layer") + 1:
+                                         parts.index("cast")]))
+        return casts
+
+    lp, ld = lowered("hymba-1.5b")
+    casts = layer_casts(lp)
     assert {"mlp", "attn/qkv", "attn/out", "ssm", "mix"} <= casts, casts
+    assert layer_casts(ld) == set()

@@ -206,3 +206,88 @@ def test_scopes_leave_the_v5e_program_unchanged(one_chip, monkeypatch, arch):
     assert kernels, "no Pallas kernel in the program"
     assert kernels == set(re.findall(
         r"%([A-Za-z0-9_]+_pallas)\.\d+ = .*custom-call", off))
+
+
+@pytest.mark.parametrize("arch,n_layers,batch,prompt_len,gen", [
+    ("hymba-1.5b", 32, 4, 2048, 16),
+    ("starcoder2-3b", 15, 2, 3840, 32),
+])
+def test_decode_reads_the_weights_prefill_hands_over_on_one_v5e(
+        one_chip, monkeypatch, arch, n_layers, batch, prompt_len, gen):
+    """The tuned serving programs at full width and a long-prompt shape,
+    compiled for one v5e as a server runs them (greedy token out, the
+    decode state donated): prefill returns the bf16 layer weights and
+    decode reads them where prefill left them.  The decode step converts
+    no stacked weight, neither program copies or transposes a whole
+    carried weight, decode takes each carried weight in the layout
+    prefill gives it, and each program fits the chip's HBM."""
+    import dataclasses
+    import functools
+    import re
+    import jax.numpy as jnp
+    from repro import tuning_cache
+    from repro.core.hw import TPU_V5E
+    from repro.distributed import make_serve_fns
+    from repro.models import build_model
+    from repro.models.layers import use_tuned_layers
+
+    cfg = dataclasses.replace(get_config(arch), n_layers=n_layers)
+    model = build_model(cfg)
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(model.abstract_params())
+    tokens = on_chip(jax.ShapeDtypeStruct((batch, prompt_len), jnp.int32))
+    prefill, decode = make_serve_fns(model)
+    prefill = functools.partial(prefill, max_len=prompt_len + gen)
+
+    def greedy(logits):
+        return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+
+    def serve_prefill(params, tokens):
+        logits, cache = prefill(params, {"tokens": tokens})
+        return greedy(logits), cache
+
+    def serve_decode(params, cache, tok):
+        logits, cache = decode(params, cache, tok)
+        return greedy(logits), cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    db = TuningDatabase()
+    tuning_cache.thaw()
+    tuning_cache.set_default_db(db)
+    try:
+        GraphTuner.tune_config(cfg, batch=batch, prompt_len=prompt_len, db=db)
+        tuning_cache.freeze()
+        with use_tuned_layers():
+            pc = jax.jit(serve_prefill).lower(params, tokens).compile()
+            tok, cache = on_chip(jax.eval_shape(serve_prefill, params, tokens))
+            dc = jax.jit(serve_decode, donate_argnums=(1,)).lower(
+                params, cache, tok).compile()
+    finally:
+        tuning_cache.thaw()
+        tuning_cache.reset_default_db()
+        monkeypatch.undo()
+        jax.clear_caches()
+
+    carried = jax.tree.leaves(cache["weights"])
+    assert len(carried) > 0
+    shapes = {",".join(map(str, x.shape)) for x in carried}
+    for name, c in (("prefill", pc), ("decode", dc)):
+        text = c.as_text()
+        moved = [shape for shape, _ in re.findall(
+            r"= bf16\[([\d,]*)\]\{[^}]*\} (copy|transpose)\(", text)
+            if shape in shapes]
+        assert moved == [], (name, moved)
+        mem = c.memory_analysis()
+        used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+        assert used < TPU_V5E.hbm_bytes, (name, used)
+    converts = [shape for shape in re.findall(
+        r"= bf16\[([\d,]*)\]\{[^}]*\} convert\(", dc.as_text())
+        if shape in shapes]
+    assert converts == [], converts
+    handed = jax.tree.leaves(pc.output_formats[1]["weights"])
+    taken = jax.tree.leaves(dc.input_formats[0][1]["weights"])
+    assert [f.layout for f in taken] == [f.layout for f in handed]
