@@ -13,7 +13,7 @@ from repro.models.config import LM_SHAPES, ModelConfig, ShapeSpec
 
 _MODULES: Dict[str, str] = {
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "hymba-1.5b": "hymba_1_5b",
     "mamba2-1.3b": "mamba2_1_3b",
     "starcoder2-3b": "starcoder2_3b",

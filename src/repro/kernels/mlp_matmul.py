@@ -141,7 +141,9 @@ def _stream_kernel(x_ref, g_ref, u_ref, o_ref, *, act):
 def _stream_analysis(p, *, m: int, d: int, f: int, act: str = "silu",
                      dtype: str = "float32"):
     """Whole-D panels: one grid step per output tile, single output
-    flush, zero scratch — per-step footprint scales with D."""
+    flush, no scratch buffer — per-step footprint scales with D.  The
+    body's float32 tiles (both dot results, the activation and the gated
+    product) live in VMEM beside the panels, so they count against it."""
     bm = np.minimum(np.asarray(p["bm"], dtype=np.int64), m)
     bn = np.minimum(np.asarray(p["bn"], dtype=np.int64), f)
     return dict(
@@ -155,6 +157,7 @@ def _stream_analysis(p, *, m: int, d: int, f: int, act: str = "silu",
         vpu_per_step=4.0 * bm * bn,
         trans_per_step=1.0 * bm * bn,
         grid_steps=cdiv(m, bm) * cdiv(f, bn),
+        scratch_bytes=4 * bm * bn * 4,
     )
 
 

@@ -76,6 +76,12 @@ _CACHE_DIMS = {
     "v": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
     "k_pre": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
     "v_pre": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
+    # latent attention: the latent and the shared rope key have no head
+    # axis; every model shard reads them whole (replicated over model)
+    "c_kv": ("layers", "batch", "cache_seq", None),
+    "k_rope": ("layers", "batch", "cache_seq", None),
+    "c_kv_pre": ("layers", "batch", "cache_seq", None),
+    "k_rope_pre": ("layers", "batch", "cache_seq", None),
     "ek": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
     "ev": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
     "ssm": ("layers", "batch", "ssm_inner", None, None),

@@ -29,10 +29,23 @@ class ModelConfig:
     n_shared: int = 0
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
-    first_dense_layers: int = 0     # leading dense layers (moonshot style)
+    first_dense_layers: int = 0     # leading dense layers (DeepSeek style)
     moe_dispatch: str = "flat"      # flat | grouped (GShard-style)
     pad_experts_to: int = 0         # pad expert dim for TP divisibility
                                     # (padded experts never routed to)
+    router_scoring: str = "softmax"  # softmax | sigmoid (DeepSeek-V3
+                                    # noaux_tc: a bias picks, the
+                                    # unbiased scores weigh)
+    routed_scaling: float = 1.0     # factor on the routed experts' weights
+    experts_held: Optional[Tuple[int, int]] = None
+                                    # [first, stop) of the router's experts
+                                    # whose weights this program holds
+                                    # (one chip's share); None = all
+    # latent attention (MLA; kv_lora_rank 0 = none)
+    kv_lora_rank: int = 0           # width of the cached latent c_kv
+    qk_nope_head_dim: int = 0       # q/k width without position
+    qk_rope_head_dim: int = 0       # q/k width with RoPE (one shared k)
+    v_head_dim: int = 0
     # ssm (mamba2 / hybrid branch)
     ssm_state: int = 0
     ssm_conv: int = 4
@@ -55,9 +68,33 @@ class ModelConfig:
     # modality stub note ([audio]/[vlm] frontends per the assignment)
     frontend: str = "tokens"        # tokens | frames
 
+    def __post_init__(self):
+        if self.experts_held is not None:   # a JSON list reads as a tuple
+            first, stop = (int(x) for x in self.experts_held)
+            if not 0 <= first < stop <= self.n_experts:
+                raise ValueError(f"experts_held {self.experts_held} is not "
+                                 f"a range of the {self.n_experts} experts")
+            object.__setattr__(self, "experts_held", (first, stop))
+
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def n_experts_held(self) -> int:
+        if self.experts_held is None:
+            return self.n_experts
+        return self.experts_held[1] - self.experts_held[0]
+
+    def attn_params(self) -> int:
+        """Weights of one attention layer."""
+        d, h, hd = self.d_model, self.n_heads, self.hd
+        if self.kv_lora_rank:
+            r, qk = self.kv_lora_rank, self.qk_nope_head_dim
+            rope, v = self.qk_rope_head_dim, self.v_head_dim
+            return (d * h * (qk + rope) + d * (r + rope) + r
+                    + r * h * (qk + v) + h * v * d)
+        return d * hd * (self.n_heads + 2 * self.n_kv) + self.n_heads * hd * d
 
     def num_params(self) -> int:
         """Total parameter count (embedding + blocks + head)."""
@@ -67,16 +104,14 @@ class ModelConfig:
         hd = self.hd
         per_layer = 0
         if self.family in ("dense", "moe", "hybrid", "encdec"):
-            attn = d * hd * (self.n_heads + 2 * self.n_kv) \
-                + self.n_heads * hd * d
-            per_layer += attn + 2 * d             # + norms
+            per_layer += self.attn_params() + 2 * d   # + norms
         if self.family in ("dense", "hybrid", "encdec"):
             glu = 3 if self.act.endswith("_glu") else 2
             per_layer += glu * d * self.d_ff
         if self.family == "moe":
             glu = 3
             expert = glu * d * self.d_ff_expert
-            per_layer += self.n_experts * expert + d * self.n_experts
+            per_layer += self.n_experts_held * expert + d * self.n_experts
             per_layer += self.n_shared * glu * d * self.d_ff_expert
         if self.family in ("ssm", "hybrid"):
             di = self.ssm_expand * d if self.family == "ssm" else \
@@ -89,7 +124,7 @@ class ModelConfig:
             # replace moe ffn by dense ffn in the leading layers
             glu = 3
             n -= self.first_dense_layers * (
-                self.n_experts * glu * d * self.d_ff_expert
+                self.n_experts_held * glu * d * self.d_ff_expert
                 + d * self.n_experts
                 + self.n_shared * glu * d * self.d_ff_expert)
             n += self.first_dense_layers * glu * d * self.d_ff
@@ -107,8 +142,9 @@ class ModelConfig:
         glu = 3
         expert = glu * d * self.d_ff_expert
         total = self.num_params()
+        active = self.top_k * self.n_experts_held / self.n_experts
         inactive = (self.n_layers - self.first_dense_layers) * \
-            (self.n_experts - self.top_k) * expert
+            (self.n_experts_held - active) * expert
         return int(total - inactive)
 
 

@@ -30,10 +30,12 @@ from repro import obs
 from repro.distributed.sharding import Sharder
 from repro.models.params import Param, param
 
-__all__ = ["cast", "count_cast_bytes", "FLOAT32_READS", "rms_norm",
+__all__ = ["cast", "count_cast_bytes", "repeated", "traced_count",
+           "FLOAT32_READS", "rms_norm",
            "make_rope", "apply_rope", "init_attention", "attention",
-           "attention_decode", "init_mlp", "mlp", "causal_mask_bias", "AttnConfig", "set_tuned_layers",
-           "use_tuned_layers", "tuned_layers_enabled"]
+           "attention_decode", "init_mla", "mla_attention", "mla_decode",
+           "init_mlp", "mlp", "causal_mask_bias", "AttnConfig",
+           "set_tuned_layers", "use_tuned_layers", "tuned_layers_enabled"]
 
 
 # ---------------------------------------------------------------------------
@@ -84,29 +86,42 @@ def _ops():
 # float32) and the MoE router.  Every other leaf is read through
 # `cast(w, compute dtype)`.
 FLOAT32_READS = frozenset({"ln1", "ln2", "norm_a", "norm_m", "q_norm",
-                           "k_norm", "norm_w", "a_log", "dt_bias", "d_skip",
-                           "router"})
+                           "k_norm", "kv_norm", "norm_w", "a_log", "dt_bias",
+                           "d_skip", "router", "e_score_correction_bias"})
 
-_CAST_COUNT: "ContextVar[Optional[Tuple[str, int]]]" = ContextVar(
-    "repro_cast_count", default=None)
+_CAST_COUNTER: "ContextVar[Optional[str]]" = ContextVar(
+    "repro_cast_counter", default=None)
+_TRIPS: "ContextVar[int]" = ContextVar("repro_trace_trips", default=1)
 
 
 @contextlib.contextmanager
-def count_cast_bytes(counter: Optional[str] = None, times: int = 1):
-    """While a program is traced inside, `cast` adds to the `repro.obs`
-    counter ``counter`` the stored bytes of each weight it converts.  A
-    layer scan traces its body once for all its trips: it passes its
-    length as ``times`` and no counter, which scales an enclosing count."""
-    outer = _CAST_COUNT.get()
-    if counter is None:
-        inner = None if outer is None else (outer[0], outer[1] * times)
-    else:
-        inner = (counter, times)
-    tok = _CAST_COUNT.set(inner)
+def repeated(times: int):
+    """A loop traced once for all of its ``times`` trips (a layer scan)
+    traces its body inside: each `traced_count` made there stands for
+    every trip."""
+    tok = _TRIPS.set(_TRIPS.get() * int(times))
     try:
         yield
     finally:
-        _CAST_COUNT.reset(tok)
+        _TRIPS.reset(tok)
+
+
+def traced_count(name: str, n: int) -> None:
+    """Add to the `repro.obs` counter ``name``, at trace time, what one
+    run of the traced program does: ``n`` times the trips of each loop
+    around."""
+    obs.count(name, n * _TRIPS.get())
+
+
+@contextlib.contextmanager
+def count_cast_bytes(counter: str):
+    """While a program is traced inside, `cast` adds to the `repro.obs`
+    counter ``counter`` the stored bytes of each weight it converts."""
+    tok = _CAST_COUNTER.set(counter)
+    try:
+        yield
+    finally:
+        _CAST_COUNTER.reset(tok)
 
 
 def cast(w: Param, dtype) -> jax.Array:
@@ -116,9 +131,9 @@ def cast(w: Param, dtype) -> jax.Array:
     v = w.value
     if v.dtype == jnp.dtype(dtype):
         return v
-    counting = _CAST_COUNT.get()
-    if counting is not None:
-        obs.count(counting[0], counting[1] * v.size * v.dtype.itemsize)
+    counter = _CAST_COUNTER.get()
+    if counter is not None:
+        traced_count(counter, v.size * v.dtype.itemsize)
     with obs.scope("cast"):
         return v.astype(dtype)
 
@@ -185,6 +200,12 @@ class AttnConfig:
     kv_repeat: int = 1           # replicate KV heads in the decode cache
                                  # so kv*r divides the TP axis (vLLM-
                                  # style; exact GQA semantics preserved)
+    # latent attention (MLA): head_dim is then the q/k width without
+    # position; kv_lora_rank 0 = ordinary heads
+    kv_lora_rank: int = 0
+    rope_dim: int = 0            # q/k width with RoPE (k's part shared)
+    v_dim: int = 0               # value width per head
+    norm_eps: float = 1e-6       # of the latent's RMSNorm
 
 
 def init_attention(key, cfg: AttnConfig) -> Dict:
@@ -288,7 +309,7 @@ def _sdpa_chunked(q, k, v, q_positions, k_positions, window, scale,
         return None, _sdpa(qi, k, v, bias, scale)
 
     _, out = jax.lax.scan(body, None, (qc, qp))
-    out = out.transpose(1, 0, 2, 3, 4).reshape(b, sp, h, hd)
+    out = out.transpose(1, 0, 2, 3, 4).reshape(b, sp, h, v.shape[-1])
     return out[:, :s]
 
 
@@ -414,6 +435,134 @@ def attention_decode(p: Dict, x: jax.Array, cache_k: jax.Array,
     with obs.scope("out"):
         y = jnp.einsum("bshk,hkd->bsd", out, cast(p["wo"], x.dtype))
     return y, (cache_k, cache_v)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2/V3 with q_lora_rank null)
+# ---------------------------------------------------------------------------
+
+
+def init_mla(key, cfg: AttnConfig) -> Dict:
+    """q_proj (per head: head_dim without position, then rope_dim with
+    it), kv_a_proj_with_mqa (the latent, then the one shared rope key),
+    kv_a_layernorm, kv_b_proj held as its key half ``wk_b`` and value
+    half ``wv_b``, and o_proj."""
+    ks = jax.random.split(key, 5)
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.head_dim, cfg.rope_dim, cfg.v_dim
+    return {
+        "wq": param(ks[0], (d, h, nope + rope), ("embed", "heads",
+                                                  "head_dim")),
+        "wkv_a": param(ks[1], (d, r + rope), ("embed", "kv_lora")),
+        "kv_norm": param(ks[1], (r,), ("kv_lora",), init="ones"),
+        "wk_b": param(ks[2], (r, h, nope), ("kv_lora", "heads",
+                                            "head_dim")),
+        "wv_b": param(ks[3], (r, h, vd), ("kv_lora", "heads", "head_dim")),
+        "wo": param(ks[4], (h, vd, d), ("heads", "head_dim", "embed"),
+                    scale=1.0 / math.sqrt(h * vd)),
+    }
+
+
+def _mla_project(p: Dict, x: jax.Array, cfg: AttnConfig, positions):
+    """q (B, S, H, head_dim + rope_dim) with its rope part rotated, the
+    normed latent c_kv (B, S, kv_lora_rank) and the rotated shared key
+    k_rope (B, S, rope_dim): what the latent cache holds."""
+    r, nope = cfg.kv_lora_rank, cfg.head_dim
+    with obs.scope("q"):
+        q = jnp.einsum("bsd,dhk->bshk", x, cast(p["wq"], x.dtype))
+    with obs.scope("kv_down"):
+        kv = jnp.einsum("bsd,dc->bsc", x, cast(p["wkv_a"], x.dtype))
+    with obs.scope("kv_norm"):
+        c_kv = _rms(kv[..., :r], p["kv_norm"].value, cfg.norm_eps)
+    with obs.scope("rope"):
+        inv = make_rope(cfg.rope_dim, cfg.rope_theta)
+        q = jnp.concatenate(
+            [q[..., :nope], apply_rope(q[..., nope:], positions[:, :, None],
+                                       inv)], axis=-1)
+        k_rope = apply_rope(kv[..., r:], positions, inv)
+    return q, c_kv, k_rope
+
+
+def mla_attention(p: Dict, x: jax.Array, cfg: AttnConfig, shd: Sharder,
+                  return_kv: bool = False):
+    """Causal latent attention over the whole sequence (prefill), with the
+    heads decompressed: k = [c_kv wk_b, k_rope], v = c_kv wv_b.  The
+    tuned kernel takes one head width, so v is zero-padded to q/k's
+    (exact: the padding's output columns are dropped) and the kernel's
+    1/sqrt(width) is the published scale.  With ``return_kv``, also the
+    latent cache's entries (c_kv, k_rope)."""
+    b, s, _ = x.shape
+    h, nope, rope, vd = cfg.n_heads, cfg.head_dim, cfg.rope_dim, cfg.v_dim
+    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    q, c_kv, k_rope = _mla_project(p, x, cfg, positions)
+    with obs.scope("kv_up"):
+        k = jnp.einsum("bsc,chk->bshk", c_kv, cast(p["wk_b"], x.dtype))
+        v = jnp.einsum("bsc,chk->bshk", c_kv, cast(p["wv_b"], x.dtype))
+        k = jnp.concatenate(
+            [k, jnp.broadcast_to(k_rope[:, :, None], (b, s, h, rope))], -1)
+    q = shd.act(q, ("batch", "seq", "heads", "head_dim"))
+    k = shd.act(k, ("batch", "seq", "heads", "head_dim"))
+    v = shd.act(v, ("batch", "seq", "heads", "head_dim"))
+    scale = 1.0 / math.sqrt(nope + rope)
+    with obs.scope("core"):
+        if tuned_layers_enabled():
+            vp = jnp.pad(v, ((0, 0),) * 3 + ((0, nope + rope - vd),))
+            out = _attention_tuned(q, k, vp, True)[..., :vd]
+        elif s < cfg.dense_below:
+            out = _sdpa(q, k, v, causal_mask_bias(positions[0],
+                                                  positions[0]), scale)
+        else:
+            out = _sdpa_chunked(q, k, v, positions, positions, 0, scale,
+                                cfg.chunk_q)
+    with obs.scope("out"):
+        y = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype),
+                       cast(p["wo"], x.dtype))
+        y = shd.act(y, ("batch", "residual_seq", "embed"))
+    if return_kv:
+        return y, (shd.cache(c_kv, ("batch", "cache_seq", None)),
+                   shd.cache(k_rope, ("batch", "cache_seq", None)))
+    return y
+
+
+def mla_decode(p: Dict, x: jax.Array, c_cache: jax.Array,
+               r_cache: jax.Array, pos: jax.Array, cfg: AttnConfig,
+               shd: Sharder):
+    """One-token latent attention in the absorbed form.  x: (B, 1, D);
+    c_cache (B, S, kv_lora_rank), r_cache (B, S, rope_dim); ``pos`` the
+    scalar position written.  The query's position-free part is taken
+    through ``wk_b`` into the latent, scored against the cached latent
+    (and its rope part against the shared rope key); the weighted sum of
+    latents goes through ``wv_b`` afterwards: no head is decompressed."""
+    b, s_cache = x.shape[0], c_cache.shape[1]
+    nope = cfg.head_dim
+    positions = jnp.full((b, 1), pos, dtype=jnp.int32)
+    q, c_new, r_new = _mla_project(p, x, cfg, positions)
+    with obs.scope("cache_write"):
+        c_cache = jax.lax.dynamic_update_slice(
+            c_cache, c_new.astype(c_cache.dtype), (0, pos, 0))
+        r_cache = jax.lax.dynamic_update_slice(
+            r_cache, r_new.astype(r_cache.dtype), (0, pos, 0))
+        c_cache = shd.cache(c_cache, ("batch", "cache_seq", None))
+        r_cache = shd.cache(r_cache, ("batch", "cache_seq", None))
+    with obs.scope("absorb"):
+        q_lat = jnp.einsum("bshk,chk->bshc", q[..., :nope],
+                           cast(p["wk_b"], x.dtype))
+    with obs.scope("core"):
+        f32 = jnp.float32
+        logits = (jnp.einsum("bshc,btc->bhst", q_lat, c_cache,
+                             preferred_element_type=f32)
+                  + jnp.einsum("bshr,btr->bhst", q[..., nope:], r_cache,
+                               preferred_element_type=f32))
+        logits = logits / math.sqrt(nope + cfg.rope_dim)
+        ok = jnp.arange(s_cache, dtype=jnp.int32) <= pos
+        logits = jnp.where(ok, logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(c_cache.dtype)
+        o_lat = jnp.einsum("bhst,btc->bshc", probs, c_cache)
+    with obs.scope("unabsorb"):
+        o = jnp.einsum("bshc,chk->bshk", o_lat, cast(p["wv_b"], x.dtype))
+    with obs.scope("out"):
+        y = jnp.einsum("bshk,hkd->bsd", o, cast(p["wo"], x.dtype))
+    return y, (c_cache, r_cache)
 
 
 # ---------------------------------------------------------------------------

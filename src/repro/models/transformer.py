@@ -2,8 +2,8 @@
 
 One scan-over-layers with stacked Params keeps the HLO size O(1 layer)
 for every assigned arch (80-layer qwen1.5-110b compiles in seconds);
-per-layer heterogeneity (hymba's sliding-vs-global windows, moonshot's
-leading dense layers) is expressed as scanned per-layer scalars or a
+per-layer heterogeneity (hymba's sliding-vs-global windows, moonlight's
+leading dense layer) is expressed as scanned per-layer scalars or a
 small prefix stack, never as unrolled layers.
 """
 from __future__ import annotations
@@ -21,7 +21,9 @@ from repro.distributed.sharding import Sharder
 from repro.models.config import ModelConfig
 from repro.models.layers import (FLOAT32_READS, AttnConfig, attention,
                                  attention_decode, cast, count_cast_bytes,
-                                 init_attention, init_mlp, mlp, rms_norm)
+                                 init_attention, init_mla, init_mlp,
+                                 mla_attention, mla_decode, mlp, repeated,
+                                 rms_norm)
 from repro.models.moe import init_moe, moe_layer
 from repro.models.params import Param, param, stack_dims, tree_param_bytes
 from repro.models.ssd import (SsdConfig, init_ssd, init_ssd_state,
@@ -35,10 +37,25 @@ __all__ = ["attn_config", "ssd_config", "init_lm", "lm_logits", "lm_loss",
 def attn_config(cfg: ModelConfig) -> AttnConfig:
     return AttnConfig(
         d_model=cfg.d_model, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
-        head_dim=cfg.hd, qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm,
+        head_dim=cfg.qk_nope_head_dim if cfg.kv_lora_rank else cfg.hd,
+        qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm,
         rope_theta=cfg.rope_theta, kv_repeat=cfg.kv_repeat,
         window=0,  # per-layer windows flow through window_override
+        kv_lora_rank=cfg.kv_lora_rank, rope_dim=cfg.qk_rope_head_dim,
+        v_dim=cfg.v_head_dim, norm_eps=cfg.norm_eps,
     )
+
+
+def attn_state(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
+    """Name -> per-position shape of what the decode cache holds for one
+    attention layer: the latent ``c_kv`` and the shared rotated key
+    ``k_rope`` for latent attention, else each KV head's ``k`` and
+    ``v``."""
+    if cfg.kv_lora_rank:
+        return {"c_kv": (cfg.kv_lora_rank,),
+                "k_rope": (cfg.qk_rope_head_dim,)}
+    kv = cfg.n_kv * max(cfg.kv_repeat, 1)
+    return {"k": (kv, cfg.hd), "v": (kv, cfg.hd)}
 
 
 def ssd_config(cfg: ModelConfig) -> SsdConfig:
@@ -73,12 +90,15 @@ def _init_block(key, cfg: ModelConfig, moe: bool) -> Dict:
                               init="ones")}
     fam = cfg.family
     if fam in ("dense", "moe", "hybrid", "encdec"):
-        blk["attn"] = init_attention(ks[1], attn_config(cfg))
+        init_attn = init_mla if cfg.kv_lora_rank else init_attention
+        blk["attn"] = init_attn(ks[1], attn_config(cfg))
         blk["ln2"] = param(ks[2], (cfg.d_model,), ("embed",), init="ones")
         if moe:
             blk["moe"] = init_moe(ks[3], cfg.d_model, cfg.d_ff_expert,
                                   cfg.n_experts, cfg.n_shared, cfg.act,
-                                  pad_to=cfg.pad_experts_to)
+                                  pad_to=cfg.pad_experts_to,
+                                  held=cfg.experts_held,
+                                  scoring=cfg.router_scoring)
         else:
             blk["mlp"] = init_mlp(ks[3], cfg.d_model, cfg.d_ff, cfg.act)
     if fam == "ssm":
@@ -151,7 +171,7 @@ def _block_apply(blk: Dict, h: jax.Array, window, cfg: ModelConfig,
     kv = sstate = None
     fam = cfg.family
     if fam == "ssm":
-        x = rms_norm(h, blk["ln1"])
+        x = rms_norm(h, blk["ln1"], cfg.norm_eps)
         with obs.scope("ssm"):
             if collect_kv:
                 y, sstate = ssd_block(blk["ssd"], x, ssd_config(cfg), shd,
@@ -159,14 +179,16 @@ def _block_apply(blk: Dict, h: jax.Array, window, cfg: ModelConfig,
             else:
                 y = ssd_block(blk["ssd"], x, ssd_config(cfg), shd)
         return h + y, aux, (kv, sstate)
-    x = rms_norm(h, blk["ln1"])
+    x = rms_norm(h, blk["ln1"], cfg.norm_eps)
     with obs.scope("attn"):
-        if collect_kv:
-            a, kv = attention(blk["attn"], x, acfg, shd,
-                              window_override=window, return_kv=True)
+        if cfg.kv_lora_rank:
+            a = mla_attention(blk["attn"], x, acfg, shd,
+                              return_kv=collect_kv)
         else:
             a = attention(blk["attn"], x, acfg, shd,
-                          window_override=window)
+                          window_override=window, return_kv=collect_kv)
+        if collect_kv:
+            a, kv = a
     if fam == "hybrid":
         with obs.scope("ssm"):
             if collect_kv:
@@ -177,18 +199,25 @@ def _block_apply(blk: Dict, h: jax.Array, window, cfg: ModelConfig,
         h = h + _mix(blk, a, m, h.dtype)
     else:
         h = h + a
-    x2 = rms_norm(h, blk["ln2"])
+    y, aux = _ffn(blk, rms_norm(h, blk["ln2"], cfg.norm_eps), cfg, shd, moe)
+    return h + y, aux, (kv, sstate)
+
+
+def _ffn(blk: Dict, x: jax.Array, cfg: ModelConfig, shd: Sharder,
+         moe: bool):
+    """The layer's MLP or expert layer: (y, aux_loss)."""
     with obs.scope("mlp"):
         if moe:
-            y, aux = moe_layer(blk["moe"], x2, n_experts=cfg.n_experts,
-                               top_k=cfg.top_k,
-                               capacity_factor=cfg.capacity_factor,
-                               act=cfg.act, shd=shd,
-                               pad_to=cfg.pad_experts_to,
-                               dispatch=cfg.moe_dispatch)
-        else:
-            y = mlp(blk["mlp"], x2, cfg.act, shd)
-    return h + y, aux, (kv, sstate)
+            return moe_layer(blk["moe"], x, n_experts=cfg.n_experts,
+                             top_k=cfg.top_k,
+                             capacity_factor=cfg.capacity_factor,
+                             act=cfg.act, shd=shd,
+                             pad_to=cfg.pad_experts_to,
+                             dispatch=cfg.moe_dispatch,
+                             scoring=cfg.router_scoring,
+                             routed_scaling=cfg.routed_scaling,
+                             held=cfg.experts_held)
+        return mlp(blk["mlp"], x, cfg.act, shd), jnp.zeros((), jnp.float32)
 
 
 def _mix(blk: Dict, a: jax.Array, m: jax.Array, dtype) -> jax.Array:
@@ -222,8 +251,9 @@ def _scan_blocks(blocks, h, windows, cfg: ModelConfig, shd: Sharder,
         body = jax.checkpoint(
             body, prevent_cse=False,
             policy=jax.checkpoint_policies.dots_saveable)
-    (h, aux), kvs = jax.lax.scan(body, (h, jnp.zeros((), jnp.float32)),
-                                 (blocks, windows))
+    with repeated(windows.shape[0]):
+        (h, aux), kvs = jax.lax.scan(body, (h, jnp.zeros((), jnp.float32)),
+                                     (blocks, windows))
     return h, aux, kvs
 
 
@@ -242,9 +272,9 @@ def _embed(params, tokens, cfg: ModelConfig, shd: Sharder, dtype,
         return shd.act(h, ("batch", "residual_seq", "embed"))
 
 
-def _head(params, h: jax.Array) -> jax.Array:
+def _head(params, h: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Final norm, then the logits over the vocabulary."""
-    h = rms_norm(h, params["final_norm"])
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     with obs.scope("head"):
         return jnp.einsum("bsd,dv->bsv", h, cast(params["lm_head"], h.dtype))
 
@@ -275,7 +305,7 @@ def lm_logits(params: Dict, tokens: jax.Array, cfg: ModelConfig,
                                moe=(cfg.family == "moe"),
                                collect_kv=collect_kv)
     aux_total += aux
-    logits = shd.act(_head(params, h), ("batch", "seq", "vocab"))
+    logits = shd.act(_head(params, h, cfg), ("batch", "seq", "vocab"))
     if collect_kv:
         return logits, aux_total, (kv_pre, kvs)
     return logits, aux_total
@@ -364,15 +394,16 @@ def init_lm_cache(cfg: ModelConfig, batch: int, seq_len: int,
               if cfg.family == "moe" else cfg.n_layers)
     cache: Dict = {"pos": jnp.zeros((), jnp.int32)}
     sc = _cache_len(cfg, seq_len)
-    kv, hd = cfg.n_kv * max(cfg.kv_repeat, 1), cfg.hd
     if cfg.family in ("dense", "moe", "hybrid"):
-        cache["k"] = jnp.zeros((n_main, batch, sc, kv, hd), dtype)
-        cache["v"] = jnp.zeros((n_main, batch, sc, kv, hd), dtype)
-        if cfg.first_dense_layers:
-            cache["k_pre"] = jnp.zeros((cfg.first_dense_layers, batch, sc,
-                                        kv, hd), dtype)
-            cache["v_pre"] = jnp.zeros((cfg.first_dense_layers, batch, sc,
-                                        kv, hd), dtype)
+        per_position = 0
+        for name, shape in attn_state(cfg).items():
+            cache[name] = jnp.zeros((n_main, batch, sc) + shape, dtype)
+            if cfg.first_dense_layers:
+                cache[name + "_pre"] = jnp.zeros(
+                    (cfg.first_dense_layers, batch, sc) + shape, dtype)
+            per_position += int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+        obs.gauge("serve.cache_bytes_per_token",
+                  per_position * (n_main + cfg.first_dense_layers))
     if cfg.family in ("ssm", "hybrid"):
         scfg = ssd_config(cfg)
         st = init_ssd_state(batch, scfg, dtype)
@@ -389,16 +420,19 @@ def _block_decode(blk, h, win, ck, cv, sstate, pos, cfg: ModelConfig,
     acfg = attn_config(cfg)
     fam = cfg.family
     if fam == "ssm":
-        x = rms_norm(h, blk["ln1"])
+        x = rms_norm(h, blk["ln1"], cfg.norm_eps)
         with obs.scope("ssm"):
             y, sstate = ssd_decode(blk["ssd"], x, sstate, ssd_config(cfg),
                                    shd)
         return h + y, (ck, cv, sstate)
-    x = rms_norm(h, blk["ln1"])
+    x = rms_norm(h, blk["ln1"], cfg.norm_eps)
     with obs.scope("attn"):
-        a, (ck, cv) = attention_decode(blk["attn"], x, ck, cv, pos, acfg,
-                                       shd, window_override=win,
-                                       rolling=(fam == "hybrid"))
+        if cfg.kv_lora_rank:
+            a, (ck, cv) = mla_decode(blk["attn"], x, ck, cv, pos, acfg, shd)
+        else:
+            a, (ck, cv) = attention_decode(blk["attn"], x, ck, cv, pos,
+                                           acfg, shd, window_override=win,
+                                           rolling=(fam == "hybrid"))
     if fam == "hybrid":
         with obs.scope("ssm"):
             m, sstate = ssd_decode(blk["ssd"], x, sstate, ssd_config(cfg),
@@ -406,17 +440,7 @@ def _block_decode(blk, h, win, ck, cv, sstate, pos, cfg: ModelConfig,
         h = h + _mix(blk, a, m, h.dtype)
     else:
         h = h + a
-    x2 = rms_norm(h, blk["ln2"])
-    with obs.scope("mlp"):
-        if moe:
-            y, _ = moe_layer(blk["moe"], x2, n_experts=cfg.n_experts,
-                             top_k=cfg.top_k,
-                             capacity_factor=cfg.capacity_factor,
-                             act=cfg.act, shd=shd,
-                             pad_to=cfg.pad_experts_to,
-                             dispatch=cfg.moe_dispatch)
-        else:
-            y = mlp(blk["mlp"], x2, cfg.act, shd)
+    y, _ = _ffn(blk, rms_norm(h, blk["ln2"], cfg.norm_eps), cfg, shd, moe)
     return h + y, (ck, cv, sstate)
 
 
@@ -468,33 +492,34 @@ def _decode_step(params: Dict, cache: Dict, token: jax.Array,
         xs = (blocks, windows,
               k if has_attn else zeros, v if has_attn else zeros,
               ssm if has_ssm else zeros, conv if has_ssm else zeros)
-        with count_cast_bytes(times=n):
+        with repeated(n):
             h, ys = jax.lax.scan(body, h, xs)
         return h, ys
 
     n_main = (cfg.n_layers - cfg.first_dense_layers
               if fam == "moe" else cfg.n_layers)
-    sc = cache["k"].shape[2] if "k" in cache else 0
+    kn, vn = attn_state(cfg)
+    sc = cache[kn].shape[2] if kn in cache else 0
     if "prefix_blocks" in params:
         npre = cfg.first_dense_layers
         h, ys = scan_stack(_overlay(params["prefix_blocks"],
                                     carried.get("prefix_blocks")), h,
                            jnp.full((npre,), max(sc, 1), jnp.int32),
-                           cache["k_pre"], cache["v_pre"], None, None,
-                           moe=False)
-        new_cache["k_pre"], new_cache["v_pre"] = ys[0], ys[1]
+                           cache[kn + "_pre"], cache[vn + "_pre"], None,
+                           None, moe=False)
+        new_cache[kn + "_pre"], new_cache[vn + "_pre"] = ys[0], ys[1]
     windows = hybrid_windows(cfg, max(sc, 1), n_main)
     h, ys = scan_stack(_overlay(params["blocks"], carried.get("blocks")),
                        h, windows,
-                       cache.get("k"), cache.get("v"),
+                       cache.get(kn), cache.get(vn),
                        cache.get("ssm"), cache.get("conv"),
                        moe=(fam == "moe"))
-    if "k" in cache:
-        new_cache["k"], new_cache["v"] = ys[0], ys[1]
+    if kn in cache:
+        new_cache[kn], new_cache[vn] = ys[0], ys[1]
     if "ssm" in cache:
         new_cache["ssm"], new_cache["conv"] = ys[2], ys[3]
     new_cache["pos"] = pos + 1
-    return _head(params, h), new_cache
+    return _head(params, h, cfg), new_cache
 
 
 def lm_prefill(params: Dict, tokens: jax.Array, cfg: ModelConfig,
@@ -527,12 +552,13 @@ def lm_prefill(params: Dict, tokens: jax.Array, cfg: ModelConfig,
 
 def _fill_cache(cfg: ModelConfig, b: int, s: int, max_len: int, pre_ys,
                 main_ys) -> Dict:
-    """The decode cache, sized for ``max_len``, holding the KV and SSM
-    states that a prefill of ``s`` positions collected."""
+    """The decode cache, sized for ``max_len``, holding the attention and
+    SSM states that a prefill of ``s`` positions collected."""
     cache = init_lm_cache(cfg, b, max_len)
+    kn, vn = attn_state(cfg)
 
     def fill_kv(kvs, kname, vname):
-        k, v = kvs  # (L, B, S, KV, hd)
+        k, v = kvs  # (L, B, S, ...)
         sc = cache[kname].shape[2]
         if sc == s:
             cache[kname] = k.astype(cache[kname].dtype)
@@ -553,16 +579,12 @@ def _fill_cache(cfg: ModelConfig, b: int, s: int, max_len: int, pre_ys,
 
     if main_ys is not None:
         kvs, sstates = main_ys
-        if kvs is not None and "k" in cache:
-            fill_kv(kvs, "k", "v")
+        if kvs is not None and kn in cache:
+            fill_kv(kvs, kn, vn)
         if sstates is not None and "ssm" in cache:
             cache["ssm"] = sstates["ssm"].astype(cache["ssm"].dtype)
             cache["conv"] = sstates["conv"].astype(cache["conv"].dtype)
-    if pre_ys is not None and "k_pre" in cache:
-        kvs_pre, _ = pre_ys
-        if kvs_pre is not None:
-            kp, vp = kvs_pre
-            cache["k_pre"] = kp.astype(cache["k_pre"].dtype)
-            cache["v_pre"] = vp.astype(cache["v_pre"].dtype)
+    if pre_ys is not None and pre_ys[0] is not None and kn + "_pre" in cache:
+        fill_kv(pre_ys[0], kn + "_pre", vn + "_pre")
     cache["pos"] = jnp.asarray(s, jnp.int32)
     return cache

@@ -5,7 +5,9 @@
   own window), records the span it nests in as ``parent``, and sits on
   the profiler's host timeline too (a `jax.profiler.TraceAnnotation` of
   the same name) whenever a profiler is running.
-- `count(name, n)` adds to a counter.
+- `count(name, n)` adds to a counter; `gauge(name, value)` sets one to
+  a property of the program being traced, the same on every trace (the
+  last value set is kept).
 - `spans()`, `counters()` read the store; `reset()` clears it and
   `reset_counters(prefix)` clears one family of counters.  Nothing is
   written out unless a caller asks.
@@ -33,7 +35,7 @@ from typing import Any, Dict, Iterator, List, Optional
 
 import jax
 
-__all__ = ["span", "count", "spans", "counters", "reset", "reset_counters",
+__all__ = ["span", "count", "gauge", "spans", "counters", "reset", "reset_counters",
            "scope", "seconds", "self_ns", "COMPILE_EVENT"]
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -76,6 +78,11 @@ def span(name: str, **attrs: Any) -> Iterator[Dict[str, Any]]:
 def count(name: str, n: int = 1) -> None:
     with _lock:
         _counters[name] += n
+
+
+def gauge(name: str, value: int) -> None:
+    with _lock:
+        _counters[name] = value
 
 
 def spans() -> List[Dict[str, Any]]:

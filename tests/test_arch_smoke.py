@@ -79,13 +79,16 @@ def test_prefill_decode(arch):
     assert int(cache2["pos"]) == int(cache["pos"]) + 1
 
 
-@pytest.mark.parametrize("arch", ["gemma-7b", "hymba-1.5b"])
+@pytest.mark.parametrize("arch", ["gemma-7b", "hymba-1.5b",
+                                  "moonlight-16b-a3b"])
 def test_decode_past_prompt_matches_longer_prefill(arch):
     """Serving sizes the cache for prompt + generated tokens: decoding
     G tokens after a P-token prefill gives the last logits of a prefill
     of all P + G tokens (dense: linear cache; hybrid: ring cache whose
-    global layers must keep the prompt's head).  The hybrid bound
-    covers the bf16 chunked-scan vs recurrent SSM state update."""
+    global layers must keep the prompt's head; moonlight: the latent
+    caches of the dense first layer and of the expert layers).  The
+    hybrid bound covers the bf16 chunked-scan vs recurrent SSM state
+    update."""
     from repro.distributed import make_serve_fns
     p_len, gen = 16, 4
     cfg = get_smoke(arch)

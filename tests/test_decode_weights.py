@@ -12,7 +12,10 @@ builds a decode state agrees on its structure and sharding.
 
 ``python tests/test_decode_weights.py --record`` rewrites the data file
 from the program as it stands; it was written at the commit before the
-carried copy and is not to be rewritten after it.
+carried copy and is not to be rewritten after it.  The
+``moonlight-16b-a3b`` entries were recorded when that configuration
+came in (latent attention and the held-experts layer), from the program
+that introduced it, with the carried copy.
 """
 import functools
 import json
@@ -30,7 +33,7 @@ from repro.configs import get_smoke
 from repro.distributed import make_serve_fns
 from repro.models import build_model
 
-ARCHS = ["hymba-1.5b", "starcoder2-3b", "moonshot-v1-16b-a3b", "mamba2-1.3b"]
+ARCHS = ["hymba-1.5b", "starcoder2-3b", "moonlight-16b-a3b", "mamba2-1.3b"]
 STORED = ["float32", "bfloat16"]
 BATCH, PROMPT, STEPS, SEED = 2, 12, 8, 1234
 DATA = os.path.join(os.path.dirname(__file__), "data", "decode_parent.npz")
@@ -241,7 +244,7 @@ from repro.models.params import Param
 
 mesh = make_mesh((2, 4), ("data", "model"))
 out = {}
-for arch in ("hymba-1.5b", "moonshot-v1-16b-a3b"):
+for arch in ("hymba-1.5b", "moonlight-16b-a3b"):
     model = build_model(get_smoke(arch))
     params, cache, tok = cell_inputs(model, ShapeSpec("d", 32, 8, "decode"),
                                      mesh)

@@ -45,13 +45,42 @@ def test_moe_matches_dense_loop_reference():
 
 
 def test_moe_capacity_drops_are_bounded():
-    """With tiny capacity the layer still runs and outputs are finite."""
+    """The capacity dispatch (what a mesh that shards the experts axis
+    runs) with tiny capacity still runs, its outputs are finite, and the
+    rows past capacity are dropped: the output differs from the
+    dropless layer's."""
+    from repro.models.moe import _capacity, moe_capacity, route
     key = jax.random.PRNGKey(1)
-    p = init_moe(key, 8, 16, 4, n_shared=1)
-    x = jax.random.normal(key, (1, 32, 8), jnp.float32)
-    y, aux = moe_layer(p, x, n_experts=4, top_k=2, capacity_factor=0.25,
-                       act="silu_glu", shd=SHD)
+    p = init_moe(key, 8, 16, 4, n_shared=0)
+    x = jax.random.normal(key, (1, 256, 8), jnp.float32)
+    w, top, _ = route(p, x, top_k=2)
+    cap = moe_capacity(256, 4, 2, 0.25)          # capacity 32, about 128 rows an expert
+    y = _capacity(x, top, w, p["w_gate"].value, p["w_up"].value,
+                  p["w_down"].value, "silu_glu", cap, SHD, "flat")
     assert np.isfinite(np.asarray(y)).all()
+    dropless, _ = moe_layer(p, x, n_experts=4, top_k=2, capacity_factor=0.25,
+                            act="silu_glu", shd=SHD)
+    assert not np.allclose(np.asarray(y), np.asarray(dropless), atol=1e-3)
+
+
+@pytest.mark.parametrize("dispatch", ["flat", "grouped"])
+def test_capacity_dispatch_with_room_matches_the_dropless_layer(dispatch):
+    """Where a mesh shards the experts axis the capacity dispatch runs;
+    with capacity to spare it drops nothing, and a share of the experts
+    (the last 4 of 8) gives what the dropless layer gives for it."""
+    from repro.models.moe import _capacity, moe_capacity, route
+    key = jax.random.PRNGKey(8)
+    p = init_moe(key, 16, 32, 8, held=(4, 8))
+    x = jax.random.normal(key, (2, 8, 16), jnp.float32)
+    w, top, _ = route(p, x, top_k=2)
+    cap = moe_capacity(8 if dispatch == "grouped" else 16, 8, 2, 8.0)
+    y = _capacity(x, top - 4, w, p["w_gate"].value, p["w_up"].value,
+                  p["w_down"].value, "silu_glu", cap, SHD, dispatch)
+    want, _ = moe_layer(p, x, n_experts=8, top_k=2, capacity_factor=8.0,
+                        act="silu_glu", shd=SHD, held=(4, 8))
+    assert float(jnp.abs(want).max()) > 1e-3
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-4,
+                               atol=2e-4)
 
 
 def _ssd_naive(p, x, cfg):

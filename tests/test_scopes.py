@@ -62,7 +62,8 @@ def scope_names(low) -> set:
     return {part for path in op_paths(low) for part in path.split("/")}
 
 
-@pytest.mark.parametrize("arch", ["hymba-1.5b", "starcoder2-3b"])
+@pytest.mark.parametrize("arch", ["hymba-1.5b", "starcoder2-3b",
+                                  "moonlight-16b-a3b"])
 @pytest.mark.parametrize("tuned", [False, True])
 def test_scopes_leave_the_optimized_program_unchanged(arch, tuned,
                                                       monkeypatch):
@@ -77,6 +78,28 @@ def test_every_scope_names_ops_of_the_serving_programs():
     lp, ld = lowered("hymba-1.5b")
     assert DECODE_NAMES <= scope_names(ld), DECODE_NAMES - scope_names(ld)
     assert PREFILL_NAMES <= scope_names(lp), PREFILL_NAMES - scope_names(lp)
+
+
+def test_latent_attention_and_expert_scopes_name_ops():
+    """Latent attention's steps in prefill (heads decompressed) and in
+    decode (absorbed), and the expert layer's, each label some op."""
+    lp, ld = lowered("moonlight-16b-a3b")
+
+    def under(low, parent):
+        """Scope names directly under ``parent``."""
+        out = set()
+        for path in op_paths(low):
+            parts = path.split("/")
+            out |= {b for a, b in zip(parts, parts[1:]) if a == parent}
+        return out
+
+    assert {"q", "kv_down", "kv_norm", "kv_up", "rope", "core",
+            "out"} <= under(lp, "attn")
+    assert {"q", "kv_down", "kv_norm", "rope", "cache_write", "absorb",
+            "core", "unabsorb", "out"} <= under(ld, "attn")
+    for low in (lp, ld):
+        assert {"router", "group", "experts", "combine",
+                "shared"} <= under(low, "mlp")
 
 
 def test_kernels_are_scoped_by_kernel_id():

@@ -98,6 +98,25 @@ def test_tuner_pick_compiles_for_v5e(one_chip, picks, arch, kernel, rows):
     assert "tpu_custom_call" in compiled.as_text(), (kernel, sig, params)
 
 
+def test_moonlight_picks_compile_for_v5e(one_chip):
+    """Every kernel instance of moonlight-16b-a3b's serving path at its
+    cell's shapes (a 64 x 1024 prefill, 64-row decode) compiles for one
+    v5e with the tuner's pick.  At 65,536 rows the ``stream`` MLP's
+    float32 tiles, beside its double-buffered panels, once put a pick
+    past the compiler's VMEM limit."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), n_layers=3,
+                              vocab=40960, experts_held=(0, 16))
+    rep = GraphTuner.tune_config(cfg, batch=64, prompt_len=1024,
+                                 db=TuningDatabase())
+    kernels = {inst["kernel"] for inst in rep["instances"]}
+    assert {"rms_norm", "flash_attention", "mlp_matmul", "matmul"} <= kernels
+    for inst in rep["instances"]:
+        compiled = _compile_pick(inst["kernel"], inst["signature"],
+                                 inst["params"], one_chip)
+        assert "tpu_custom_call" in compiled.as_text(), inst
+
+
 def test_tuned_prefill_compiles_for_one_v5e(one_chip, monkeypatch):
     """The whole jitted hymba-1.5b prefill of `chip_smoke.py` (tuned ops
     after a graph pretune, batch 4 x prompt 1024, cache sized for 16
