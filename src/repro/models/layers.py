@@ -267,8 +267,10 @@ def _sdpa(q, k, v, bias, scale):
     """q: (B,S,H,hd), k/v: (B,Sk,KV,hd) — GQA dense attention.
 
     KV heads are broadcast up to H before the einsum so the head axis
-    stays cleanly TP-sharded (the Megatron GQA recipe); XLA fuses the
-    broadcast into the matmul.  Softmax in f32, PV in the value dtype.
+    stays cleanly TP-sharded (the Megatron GQA recipe).  XLA does not
+    fuse the broadcast into the matmul: the broadcast K/V (K in float32)
+    are written out whole, which is why decode has its own grouped core
+    (`_sdpa_decode`).  Softmax in f32, PV in the value dtype.
     """
     b, s, h, hd = q.shape
     kvh = k.shape[2]
@@ -286,6 +288,31 @@ def _sdpa(q, k, v, bias, scale):
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhqs,bshd->bqhd", probs, v)
     return out.astype(q.dtype)
+
+
+def _sdpa_decode(q, cache_k, cache_v, bias, scale):
+    """q: (B,1,H,hd), cache_k/v: (B,S,KV,hd) — one-token GQA attention
+    that reads the cache as stored.
+
+    Each KV head scores its group of H//KV query heads in one grouped
+    einsum over the cache in its own dtype, accumulated in f32: no
+    broadcast up to H, no cast of the cache (a product of two bf16
+    values is exact in f32, so only the summation order can differ from
+    `_sdpa`).  The KV axis stays outermost in the group split, so a
+    heads-sharded q meets the kv-head-sharded cache group for group.
+    Bias and softmax in f32, PV in the value dtype with f32
+    accumulation."""
+    b, sq, h, hd = q.shape
+    kvh = cache_k.shape[2]
+    traced_count("attn.decode_grouped", 1)
+    qg = q.reshape(b, sq, kvh, h // kvh, hd)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, cache_k,
+                        preferred_element_type=jnp.float32) * scale
+    logits = logits + bias  # bias: (q, s) broadcast
+    probs = jax.nn.softmax(logits, axis=-1).astype(cache_v.dtype)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, cache_v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, sq, h, cache_v.shape[-1]).astype(q.dtype)
 
 
 def _sdpa_chunked(q, k, v, q_positions, k_positions, window, scale,
@@ -431,7 +458,7 @@ def attention_decode(p: Dict, x: jax.Array, cache_k: jax.Array,
                 ok &= (pos - idx) < window
             bias = jnp.where(ok, 0.0, -1e30).astype(jnp.float32)[None, :]
         scale = 1.0 / math.sqrt(cfg.head_dim)
-        out = _sdpa(q, cache_k, cache_v, bias, scale).astype(x.dtype)
+        out = _sdpa_decode(q, cache_k, cache_v, bias, scale)
     with obs.scope("out"):
         y = jnp.einsum("bshk,hkd->bsd", out, cast(p["wo"], x.dtype))
     return y, (cache_k, cache_v)
