@@ -250,8 +250,8 @@ _GENERIC_CUDA = CudaProfile()
 # ---------------------------------------------------------------------------
 
 
-# frozen: the frozen-table probe answered; live: the db/memo/service
-# resolve answered in full; fallback: largest-divisor params filled gaps;
+# frozen: the frozen-table probe answered; live: the memo/db resolve
+# answered in full; fallback: largest-divisor params filled gaps;
 # explicit: the caller passed tuned_params=; collected: recorded by
 # collect_dispatches()
 _TIERS = ("frozen", "live", "fallback", "explicit", "collected")
@@ -530,9 +530,9 @@ class KernelSpec:
 
         Variant mode contributes ``{"variants": <structural digest>}``
         so records ranked under one variant set never satisfy lookups
-        (or single-flight coalescing, or frozen-table builds) under
-        another.  The registry folds these into `make_key` for every
-        tier — client, service, and freeze agree by construction.
+        (or frozen-table builds) under another.  The registry folds
+        these into `make_key` for every tier — dispatch and freeze
+        agree by construction.
         """
         if self._variants is None:
             return {}

@@ -119,8 +119,7 @@ def variants_fingerprint(variants: Mapping[str, KernelVariant]) -> str:
     declarations.  Part of the cache-key signature (``"variants"``), so
     records ranked under one variant set can never answer dispatch for
     another — adding, removing, or re-spacing a variant changes every
-    affected digest, and the single-flight service tier (keyed on the
-    digest) never coalesces across variant sets."""
+    affected digest."""
     parts = []
     for vid in sorted(variants):
         axes = variants[vid].space

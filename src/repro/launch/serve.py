@@ -49,29 +49,6 @@ def _warm_tuning_db(db, path: str, strict: bool = False):
     return n, corrupt
 
 
-def _connect_tuning_server(url: str):
-    """Point cold dispatches at a tuning service; never fatal — an
-    unreachable service means serving starts degraded on the local
-    tiers (pretuned records, then fallback params), with a banner."""
-    from repro import tuning_cache
-    try:
-        client = tuning_cache.configure_service(url)
-    except ValueError as e:
-        print(f"[serve] WARNING: bad --tuning-server {url!r} ({e}); "
-              f"serving DEGRADED on local tiers")
-        return None
-    health = client.health()
-    if health is None:
-        print(f"[serve] WARNING: tuning service {client.url} unreachable "
-              f"— serving DEGRADED on local tiers (pretuned records, "
-              f"then fallback params)")
-    else:
-        print(f"[serve] tuning service {client.url}: "
-              f"{health.get('records', '?')} records, "
-              f"generation {health.get('generation', '?')}")
-    return client
-
-
 def serve(cfg, *, batch: int, prompt_len: int, gen: int, seed: int = 0,
           temperature: float = 0.0, tuned_ops: bool = False,
           pretune: bool = False) -> Dict[str, Any]:
@@ -238,10 +215,6 @@ def main():
     ap.add_argument("--strict-db", action="store_true",
                     help="exit non-zero if --tuning-db has corrupt "
                          "lines (default: skip them, print the count)")
-    ap.add_argument("--tuning-server", default=None, metavar="URL",
-                    help="tuning service to consult for cold dispatches "
-                         "(http://host:port); unreachable -> serve "
-                         "degraded on the local tiers")
     ap.add_argument("--tuned-ops", action="store_true",
                     help="route rms_norm / gated-mlp / full attention "
                          "through the variant-aware tuned kernel "
@@ -269,8 +242,6 @@ def main():
     db = tuning_cache.get_default_db()
     if args.tuning_db:
         _warm_tuning_db(db, args.tuning_db, strict=args.strict_db)
-    if args.tuning_server:
-        _connect_tuning_server(args.tuning_server)
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     run = serve(cfg, batch=args.batch, prompt_len=args.prompt_len,

@@ -18,11 +18,8 @@ On real multi-host TPU the detection side would key off
 ``jax.monitoring`` heartbeats per host; the policy surface here is the
 same.
 
-The when-to-fire arithmetic is shared with the tuning service's chaos
-layer: `FaultSchedule` lives in `repro.tuning_cache.service.faults`
-(re-exported here) and :func:`scheduled_fault` adapts it into an
-``inject_fault`` callback, so training-loop chaos tests and tuning
-chaos tests declare faults in one vocabulary.
+`FaultSchedule` is the declarative when-to-fire arithmetic, and
+:func:`scheduled_fault` adapts it into an ``inject_fault`` callback.
 """
 from __future__ import annotations
 
@@ -31,10 +28,35 @@ import time
 from typing import Any, Callable, Dict, Optional
 
 from repro.checkpoint.manager import CheckpointManager
-from repro.tuning_cache.service.faults import FaultSchedule
 
 __all__ = ["FaultPolicy", "FaultSchedule", "StragglerDetected",
            "TrainSupervisor", "scheduled_fault"]
+
+
+@dataclasses.dataclass(frozen=True)
+class FaultSchedule:
+    """Which hits of a site a fault fires on.
+
+    ``after`` is the first firing hit (1-based), ``every`` the repeat
+    stride from there on (0 = fire only on the ``after``-th hit), and
+    ``times`` the total fire budget (0 = unlimited).  The default fires
+    on every hit — a standing outage.
+    """
+
+    after: int = 1
+    every: int = 1
+    times: int = 0
+
+    def fires_at(self, hit: int, fired: int) -> bool:
+        """``hit`` is this site's 1-based hit counter; ``fired`` how
+        many times this fault already fired."""
+        if self.times > 0 and fired >= self.times:
+            return False
+        if hit < self.after:
+            return False
+        if self.every <= 0:
+            return hit == self.after
+        return (hit - self.after) % self.every == 0
 
 
 def scheduled_fault(schedule: FaultSchedule,

@@ -24,14 +24,11 @@ See DESIGN.md §6-§7 for the key schema and invalidation rules.
 """
 from __future__ import annotations
 
-import logging
 import os
-import threading
 from typing import Optional
 
 from repro.tuning_cache.keys import (CacheKey, MODEL_VERSION, canonical_json,
                                      fingerprint_spec, make_key)
-from repro.tuning_cache.service.client import ClientPolicy, ServiceClient
 from repro.tuning_cache.store import (CacheStats, DiskStore, TuningDatabase,
                                       TuningRecord)
 from repro.tuning_cache import registry
@@ -59,73 +56,11 @@ __all__ = [
     "freeze", "thaw", "is_frozen", "frozen_lookup", "frozen_table",
     "get_default_db", "set_default_db", "reset_default_db", "pretuned_dir",
     "pretuned_path", "warm_pretuned",
-    "configure_service", "service_client",
 ]
 
 ENV_DB_DIR = "REPRO_TUNING_CACHE_DIR"
-# URL of a tuning service (e.g. http://127.0.0.1:8137); when set, the
-# default dispatch path consults it between the live memo and the local
-# database tiers.  See DESIGN.md §13.
-ENV_SERVICE = "REPRO_TUNING_SERVICE"
 
 _default_db: Optional[TuningDatabase] = None
-
-_log = logging.getLogger(__name__)
-
-_service: Optional[ServiceClient] = None
-_service_env_checked = False
-_service_lock = threading.Lock()
-
-
-def _on_service_generation() -> None:
-    # The shared database moved under us (operator import, re-warm):
-    # our frozen tables and live memos may hold its previous answers.
-    # One local generation bump routes the thaw through the existing
-    # on_invalidate machinery — the frozen tier drops and memo entries
-    # self-invalidate against the new generation.
-    db = _default_db
-    if db is not None:
-        db.invalidate()
-
-
-def configure_service(url: Optional[str] = None, *,
-                      client: Optional[ServiceClient] = None,
-                      policy: Optional[ClientPolicy] = None
-                      ) -> Optional[ServiceClient]:
-    """Set (or, with no arguments, clear) the process tuning-service
-    client used by the default dispatch path.  Explicit configuration
-    overrides the ``REPRO_TUNING_SERVICE`` environment variable."""
-    global _service, _service_env_checked
-    if client is None and url:
-        client = ServiceClient(url, policy=policy)
-    with _service_lock:
-        old, _service = _service, client
-        _service_env_checked = True
-        if client is not None:
-            client.on_generation_change(_on_service_generation)
-    if old is not None and old is not client:
-        old.close()
-    return client
-
-
-def service_client() -> Optional[ServiceClient]:
-    """The configured tuning-service client, building one lazily from
-    ``REPRO_TUNING_SERVICE`` on first ask; ``None`` when no service is
-    configured (the normal, local-only mode)."""
-    global _service, _service_env_checked
-    if _service is not None or _service_env_checked:
-        return _service
-    with _service_lock:
-        if _service is None and not _service_env_checked:
-            _service_env_checked = True
-            url = os.environ.get(ENV_SERVICE, "").strip()
-            if url:
-                try:
-                    _service = ServiceClient(url)
-                    _service.on_generation_change(_on_service_generation)
-                except ValueError as e:
-                    _log.warning("ignoring %s=%r: %s", ENV_SERVICE, url, e)
-        return _service
 
 
 def pretuned_dir() -> str:

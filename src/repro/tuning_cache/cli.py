@@ -205,26 +205,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     p_pre.add_argument("--prompt-len", type=int, default=64,
                        help="prompt length for --config (default 64)")
 
-    p_srv = add_sub("serve",
-                    help="serve the database over HTTP (coalesced "
-                         "lookup-or-tune for a fleet of client "
-                         "processes; see DESIGN.md §13)")
-    p_srv.add_argument("--host", default="127.0.0.1")
-    p_srv.add_argument("--port", type=int, default=0,
-                       help="listen port (default 0: ephemeral — read "
-                            "it from the ready line)")
-    p_srv.add_argument("--warm-jsonl", default=None,
-                       help="JSONL to warm the served database with "
-                            "before listening")
-    p_srv.add_argument("--warm-pretuned", default=None, metavar="TARGET",
-                       help="fold in the shipped pretuned records for "
-                            "this hardware target before listening")
-    p_srv.add_argument("--fault", action="append", default=[],
-                       metavar="KIND@SITE[:K=V,...]",
-                       help="inject a chaos fault, e.g. "
-                            "delay@server.tune:delay=2.0 or "
-                            "kill@server.request:after=3 (repeatable)")
-
     args = ap.parse_args(argv)
     db = _open_db(args.db)
 
@@ -348,43 +328,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                 print(f"exported {len(mem)} records -> {out}")
         if failures:
             raise SystemExit(f"pretune --verify failed for: {failures}")
-    elif args.cmd == "serve":
-        import repro.kernels  # noqa: F401  (registers dispatch problems)
-        from repro.tuning_cache.service.faults import (FaultInjector,
-                                                       parse_fault)
-        from repro.tuning_cache.service.server import TuningServer
-        from repro.tuning_cache.store import ENV_FSYNC
-        from repro.tuning_cache import warm_pretuned
-        try:
-            injector = FaultInjector([parse_fault(t) for t in args.fault])
-        except ValueError as e:
-            raise SystemExit(f"error: {e}")
-        if db.disk is not None:
-            # a served disk store is by definition multi-process shared:
-            # records that survive a crash must be whole
-            os.environ.setdefault(ENV_FSYNC, "1")
-        if args.warm_pretuned:
-            n = warm_pretuned(db, args.warm_pretuned)
-            print(f"warmed {n} pretuned records for {args.warm_pretuned}")
-        if args.warm_jsonl:
-            try:
-                n = db.warm_jsonl(args.warm_jsonl)
-            except OSError as e:
-                raise SystemExit(f"cannot warm {args.warm_jsonl}: {e}")
-            print(f"warmed {n} records from {args.warm_jsonl}")
-        server = TuningServer(db=db, host=args.host, port=args.port,
-                              injector=injector)
-        # the ready line is machine-read (tests, process managers):
-        # flush it before blocking in serve_forever
-        print(f"[tuning-service] listening on {server.url} "
-              f"({len(db)} records resident, generation {db.generation})",
-              flush=True)
-        try:
-            server.serve_forever()
-        except KeyboardInterrupt:
-            pass
-        finally:
-            server._httpd.server_close()
     return 0
 
 

@@ -514,12 +514,10 @@ def dispatch_key(kernel_id: str, *, spec: ChipSpec, mode: str,
     """The one `CacheKey` construction every dispatch tier uses.
 
     Folds the entry's :func:`_key_extras_of` into the signature before
-    keying, so the client path (`lookup_or_tune`), the tuning service
-    (`resolve_one` — whose single-flight coalescing is keyed on the
-    resulting digest), and the frozen-table build all agree on which
-    records answer which questions.  Two variant sets of one logical op
-    can therefore never share a digest.  ``signature`` must already be
-    normalized.
+    keying, so the dispatch path (`lookup_or_tune`) and the frozen-table
+    build agree on which records answer which questions.  Two variant
+    sets of one logical op can therefore never share a digest.
+    ``signature`` must already be normalized.
     """
     extras = _key_extras_of(_REGISTRY.get(kernel_id))
     clash = set(extras) & set(signature)
@@ -850,34 +848,6 @@ def frozen_lookup(kernel_id: str, signature: Dict[str, Any], *,
 on_default_target_change(thaw)
 
 
-def _service_resolve(key: CacheKey, kernel_id: str,
-                     signature: Dict[str, Any], spec: ChipSpec,
-                     mode: str) -> Optional[TuningRecord]:
-    """Consult the configured tuning service for one kernel instance.
-
-    Returns a `TuningRecord` under *our* locally-computed key, or
-    ``None`` on miss or degradation.  Never raises — the service tier
-    is optional by contract (`ServiceClient.resolve` already absorbs
-    every transport failure; this guard covers payload surprises)."""
-    from repro.tuning_cache import service_client
-    try:
-        client = service_client()
-        if client is None:
-            return None
-        payload = client.resolve(kernel_id, dict(signature),
-                                 target=spec.name,
-                                 fingerprint=fingerprint_spec(spec),
-                                 mode=mode)
-        if payload is None or payload.get("digest") != key.digest:
-            # A digest mismatch means the server ranked under a
-            # different model/key schema: its params answer some other
-            # question, not our key.  Treat as a miss.
-            return None
-        return TuningRecord.from_dict({**payload, "key": key.to_dict()})
-    except Exception:
-        return None
-
-
 _tc = None   # the repro.tuning_cache package, bound on first dispatch
 
 
@@ -899,11 +869,13 @@ def lookup_or_tune(kernel_id: str, *,
     cache key and the dispatch memo, so per-target results are fully
     isolated.  Identical ``(kernel_id, signature, spec)`` calls after
     the first are pure cache hits: no space enumeration, no
-    static_info construction, no cost-model evaluation.  On the default
-    db/model path repeat calls are additionally memoized per process,
-    skipping even key construction — warm dispatch is a single dict
-    probe (and after :func:`freeze`, a lock-free frozen-table probe
-    with no generation check at all).
+    static_info construction, no cost-model evaluation.  Resolution is
+    local, in order: the frozen table (after :func:`freeze`, a
+    lock-free probe with no generation check), the per-process memo,
+    the database (its LRU, then its disk store), and last a cold rank
+    whose record the database keeps.  The frozen table and the memo
+    engage only on the default db/model path; there, warm dispatch is
+    a single dict probe that skips even key construction.
 
     ``model`` takes a `CostModel`/`PipelineModel` instance, a model
     *kind* name from `MODEL_KINDS` (``"eq6"`` / ``"pipeline"`` — the
@@ -916,8 +888,8 @@ def lookup_or_tune(kernel_id: str, *,
     kind: Optional[str] = None
     if isinstance(model, str):
         # a kind name is an *explicit* model request: same database
-        # semantics as passing the built model object (no memo, no
-        # service), just resolved per spec below.
+        # semantics as passing the built model object (no memo), just
+        # resolved per spec below.
         kind = _check_model_kind(model)
         model = None
     if db is None and model is None and kind is None:
@@ -935,7 +907,6 @@ def lookup_or_tune(kernel_id: str, *,
         spec = resolve_target(spec)
     memo_key = shard = None
     gen0 = 0
-    use_service = False
     if db is None:
         # parent package — circular at module-import time, so bound
         # lazily once rather than paying a per-dispatch `from ... import`
@@ -946,11 +917,8 @@ def lookup_or_tune(kernel_id: str, *,
         db = _tc.get_default_db()
         if spec.name not in db.warmed_targets:     # once per (db, target)
             _tc._warm_pretuned_spec(db, spec)
-        # Only the all-default path consults the tuning service: an
-        # explicit model (or kind) would key a digest the server
-        # (which ranks under ITS default model) can never answer.
-        use_service = model is None and kind is None
-        if use_service:         # default db + default model: memo engages
+        default_path = model is None and kind is None
+        if default_path:        # default db + default model: memo engages
             entry = _REGISTRY.get(kernel_id)
             binder = _binder_of(entry) if entry is not None else None
             # the entry's effective model kind is part of the memo key:
@@ -984,22 +952,6 @@ def lookup_or_tune(kernel_id: str, *,
     signature = normalize_signature(kernel_id, signature)
     key = dispatch_key(kernel_id, spec=spec, mode=mode,
                        model_name=model.fingerprint(), signature=signature)
-
-    if use_service:
-        # Service tier (DESIGN.md §13): between the live memo and the
-        # local database.  A hit is written through to the local tiers
-        # so later dispatches (and other processes sharing the disk
-        # store) stay warm even if the service dies; any failure —
-        # unreachable, slow, corrupt — returns None and we fall
-        # through to the local tiers below.
-        rec = _service_resolve(key, kernel_id, signature, spec, mode)
-        if rec is not None:
-            db.put(rec)
-            params = dict(rec.params)
-            if memo_key is not None:
-                with shard.lock:
-                    shard.entries[memo_key] = (gen0, dict(params))
-            return params
 
     def tune() -> TuningRecord:
         # The problem's static_info builders resolve their own spec from

@@ -43,8 +43,8 @@ _log = logging.getLogger(__name__)
 # DiskStore fsyncs each record file before the rename, so a record that
 # survives a power loss is guaranteed whole, at ~1 disk flush per tune.
 # Tunes are rare by design (the whole point of the cache), so the
-# default stays off for dev speed and on only where a shared disk store
-# feeds a serving fleet (the tuning service turns it on).
+# default stays off for dev speed; turn it on where several processes
+# share one disk store.
 ENV_FSYNC = "REPRO_TUNING_CACHE_FSYNC"
 
 
@@ -342,9 +342,8 @@ class TuningDatabase:
         """Declare the cached view of this database stale: bump
         ``generation`` and fire the invalidation hooks, keeping the
         resident records.  This is the entry point for *external* bulk
-        mutation — an operator rewrote the shared disk store, or a
-        service client saw the server's generation move — where the
-        records are still fine but every derived structure (frozen
+        mutation — an operator rewrote the shared disk store — where
+        the records are still fine but every derived structure (frozen
         tables, dispatch memos) must re-resolve."""
         with self.lock:
             self._bump_generation()

@@ -13,6 +13,7 @@ from repro.distributed import make_train_step
 from repro.models import ModelConfig, build_model
 from repro.optim import AdamWConfig, init_adamw
 from repro.runtime import FaultPolicy, TrainSupervisor
+from repro.runtime.fault import FaultSchedule, scheduled_fault
 
 CFG = ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
                   n_heads=4, n_kv=2, d_ff=64, vocab=128)
@@ -135,3 +136,26 @@ def test_data_stream_resumable_and_deterministic():
                                   s2.make_batch(17)["tokens"])
     assert not np.array_equal(s1.make_batch(17)["tokens"],
                               s1.make_batch(18)["tokens"])
+
+
+def test_fault_schedule_arithmetic():
+    s = FaultSchedule(after=2, every=3, times=2)
+    fired = 0
+    hits = [h for h in range(1, 12)
+            if s.fires_at(h, fired) and (fired := fired + 1)]
+    assert hits == [2, 5]                   # after=2, stride 3, budget 2
+    once = FaultSchedule(after=4, every=0)
+    assert [h for h in range(1, 8) if once.fires_at(h, 0)] == [4]
+    always = FaultSchedule()
+    assert all(always.fires_at(h, h - 1) for h in range(1, 5))
+
+
+def test_scheduled_fault_adapts_to_train_supervisor_hook():
+    inject = scheduled_fault(FaultSchedule(after=3, every=0),
+                             exc=lambda step: OSError(f"step {step}"))
+    inject(10)
+    inject(11)
+    with pytest.raises(OSError, match="step 12"):
+        inject(12)
+    inject(13)                              # budget-less after=3,every=0:
+    #                                         fires exactly once

@@ -11,6 +11,9 @@ fallback regression, and binder exclusion of non-compilable
 declarations.
 """
 import json
+import os
+import subprocess
+import sys
 
 import pytest
 
@@ -84,6 +87,62 @@ def test_frozen_parity_all_kernels(target):
         assert tuning_cache.frozen_lookup(kid, sig, spec=target) == live[kid]
         assert tuning_cache.frozen_lookup(
             kid, sig, spec=resolve_target(target)) == live[kid]
+
+
+# ---------------------------------------------------------------------------
+# The disk store: a fresh database on the same root answers without a tune
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("target", SHIPPED_TARGETS)
+@pytest.mark.parametrize("kid", sorted(_SIGS))
+def test_disk_store_answers_a_fresh_db(kid, target, tmp_path):
+    """A cold rank written through to a `DiskStore` answers a second
+    database on the same root (another process's view) with the same
+    params and no tune of its own."""
+    root = str(tmp_path / "db")
+    cold = TuningDatabase(root=root)
+    params = tuning_cache.lookup_or_tune(kid, spec=target, db=cold,
+                                         **_SIGS[kid])
+    assert cold.stats.tunes == 1
+    fresh = TuningDatabase(root=root)
+    assert tuning_cache.lookup_or_tune(kid, spec=target, db=fresh,
+                                       **_SIGS[kid]) == params
+    assert fresh.stats.tunes == 0 and fresh.stats.hits == 1
+
+
+_COLD_DISPATCH = """
+import json, sys
+import repro.kernels  # noqa: F401  (registers dispatch problems)
+from repro.tuning_cache import TuningDatabase, lookup_or_tune
+db = TuningDatabase(root=sys.argv[1])
+print(json.dumps(lookup_or_tune("matmul", spec="tpu-v5e", db=db,
+                                m=320, n=320, k=320)))
+"""
+
+
+def test_processes_sharing_a_disk_store_agree_on_a_cold_key(tmp_path):
+    """Two processes dispatch one cold key into one disk root at once:
+    both answer the same params, and the store ends holding one whole
+    record (atomic publish; a race costs at most a duplicate rank)."""
+    root = str(tmp_path / "db")
+    env = dict(os.environ,
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    procs = [subprocess.Popen([sys.executable, "-c", _COLD_DISPATCH, root],
+                              stdout=subprocess.PIPE, env=env, text=True)
+             for _ in range(2)]
+    outs = [p.communicate(timeout=300)[0] for p in procs]
+    assert [p.returncode for p in procs] == [0, 0]
+    answers = [json.loads(o.strip().splitlines()[-1]) for o in outs]
+    assert answers[0] == answers[1]
+    reader = TuningDatabase(root=root)
+    recs = list(reader.records())
+    assert len(recs) == 1 and recs[0].params == answers[0]
+    assert reader.disk.corrupt_seen == 0
+    # no temp file or quarantined copy left beside the writers' lock
+    assert set(os.listdir(root)) - {".lock"} == {
+        f"{recs[0].key.digest}.json"}
 
 
 def test_frozen_respects_scoped_target_override():

@@ -179,3 +179,27 @@ def test_compile_cache_dir_is_placed_from_outside(tmp_path, from_env):
                          text=True, env=env, timeout=120)
     assert out.returncode == 0, out.stderr[-2000:]
     assert out.stdout.split() == [want, want, "True"]
+
+
+def test_warm_tuning_db_reports_and_strict_exits(tmp_path, capsys):
+    from repro import tuning_cache
+    from repro.core.hw import TPU_V5E
+    from repro.launch.serve import _warm_tuning_db
+    from repro.tuning_cache import TuningDatabase
+    rec = tuning_cache.TuningRecord(
+        key=tuning_cache.make_key("matvec", spec=TPU_V5E, m=128, n=128,
+                                  dtype="float32"),
+        params={"bm": 64})
+    path = tmp_path / "mix.jsonl"
+    path.write_text(json.dumps(rec.to_dict()) + "\n"
+                    + "corrupt line one\n" + '{"params": {}}\n')
+    db = TuningDatabase()
+    assert _warm_tuning_db(db, str(path)) == (1, 2)
+    assert "2 corrupt lines skipped" in capsys.readouterr().out
+    with pytest.raises(SystemExit):
+        _warm_tuning_db(TuningDatabase(), str(path), strict=True)
+    with pytest.raises(SystemExit):         # unreadable + strict: loud
+        _warm_tuning_db(TuningDatabase(), str(tmp_path / "absent.jsonl"),
+                        strict=True)
+    assert _warm_tuning_db(TuningDatabase(),
+                           str(tmp_path / "absent.jsonl")) == (0, 0)
