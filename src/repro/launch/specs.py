@@ -20,6 +20,7 @@ from repro.models import batch_shapes
 from repro.models.config import ModelConfig, ShapeSpec
 from repro.models.model import Model
 from repro.models.params import Param, map_params
+from repro.models.transformer import cache_dims
 
 __all__ = ["sharded_params", "sharded_opt_state", "sharded_batch",
            "sharded_cache", "cell_inputs", "tree_bytes_per_device"]
@@ -71,17 +72,10 @@ def sharded_batch(cfg: ModelConfig, shape: ShapeSpec, mesh: Mesh,
     return out
 
 
+# the attention leaves' dims follow their stored axis order
+# (`transformer.cache_dims`); latent attention's latent and shared rope
+# key have no head axis, so every model shard reads them whole
 _CACHE_DIMS = {
-    "k": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
-    "v": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
-    "k_pre": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
-    "v_pre": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
-    # latent attention: the latent and the shared rope key have no head
-    # axis; every model shard reads them whole (replicated over model)
-    "c_kv": ("layers", "batch", "cache_seq", None),
-    "k_rope": ("layers", "batch", "cache_seq", None),
-    "c_kv_pre": ("layers", "batch", "cache_seq", None),
-    "k_rope_pre": ("layers", "batch", "cache_seq", None),
     "ek": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
     "ev": ("layers", "batch", "cache_seq", "kv_heads", "head_dim"),
     "ssm": ("layers", "batch", "ssm_inner", None, None),
@@ -96,12 +90,13 @@ def sharded_cache(model: Model, shape: ShapeSpec, mesh: Mesh,
     """The decode state; the layer weights it carries are sharded as
     the weights they copy."""
     acache = model.abstract_cache(shape.global_batch, shape.seq_len)
+    known = {**_CACHE_DIMS, **cache_dims(model.cfg)}
     out = {}
     for name, sds in acache.items():
         if name == "weights":
             out[name] = _shard_params(sds, mesh, weight_rules)
             continue
-        dims = _CACHE_DIMS.get(name, tuple([None] * len(sds.shape)))
+        dims = known.get(name, tuple([None] * len(sds.shape)))
         s = named_sharding(dims, sds.shape, rules, mesh)
         out[name] = _sds(sds.shape, sds.dtype, s)
     return out

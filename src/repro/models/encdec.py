@@ -21,8 +21,8 @@ import jax.numpy as jnp
 from repro.distributed.sharding import Sharder
 from repro.models.config import ModelConfig
 from repro.models.layers import (AttnConfig, attention, attention_decode,
-                                 init_attention, init_mlp, mlp, rms_norm,
-                                 _sdpa)
+                                 cache_seq_axis, init_attention, init_mlp,
+                                 mlp, rms_norm, stored_order, _sdpa)
 from repro.models.params import Param, param, stack_dims
 
 __all__ = ["init_encdec", "encdec_loss", "encdec_prefill",
@@ -207,10 +207,12 @@ def init_encdec_cache(cfg: ModelConfig, batch: int, seq_len: int,
                       dtype=None) -> Dict:
     dtype = dtype or jnp.dtype(cfg.dtype)
     kv, hd = cfg.n_kv, cfg.hd
+    self_kv = stored_order((cfg.n_layers, batch, seq_len, kv, hd),
+                           cache_seq_axis(hd))
     return {
         "pos": jnp.zeros((), jnp.int32),
-        "k": jnp.zeros((cfg.n_layers, batch, seq_len, kv, hd), dtype),
-        "v": jnp.zeros((cfg.n_layers, batch, seq_len, kv, hd), dtype),
+        "k": jnp.zeros(self_kv, dtype),
+        "v": jnp.zeros(self_kv, dtype),
         "ek": jnp.zeros((cfg.n_layers, batch, cfg.enc_seq, kv, hd), dtype),
         "ev": jnp.zeros((cfg.n_layers, batch, cfg.enc_seq, kv, hd), dtype),
     }
@@ -223,14 +225,12 @@ def encdec_prefill(params: Dict, frames: jax.Array, tokens: jax.Array,
                                  collect_kv=True)
     kvs, enc_kvs = ys
     cache = init_encdec_cache(cfg, b, max(s, max_len))
-    if cache["k"].shape[2] > s:
-        cache["k"] = cache["k"].at[:, :, :s].set(
-            kvs[0].astype(cache["k"].dtype))
-        cache["v"] = cache["v"].at[:, :, :s].set(
-            kvs[1].astype(cache["v"].dtype))
-    else:
-        cache["k"] = kvs[0].astype(cache["k"].dtype)
-        cache["v"] = kvs[1].astype(cache["v"].dtype)
+    seq = cache_seq_axis(cfg.hd)
+    for name, x in zip(("k", "v"), kvs):
+        x = jnp.moveaxis(x.astype(cache[name].dtype), 2, seq)
+        idx = [slice(None)] * x.ndim
+        idx[seq] = slice(0, s)
+        cache[name] = cache[name].at[tuple(idx)].set(x)
     cache["ek"] = enc_kvs[0].astype(cache["ek"].dtype)
     cache["ev"] = enc_kvs[1].astype(cache["ev"].dtype)
     cache["pos"] = jnp.asarray(s, jnp.int32)
@@ -243,22 +243,23 @@ def encdec_decode_step(params: Dict, cache: Dict, token: jax.Array,
     pos = cache["pos"]
     h = params["embed"].value.astype(dtype)[token]
 
-    def body(hh, xs):
-        blk, ck, cv, ek, ev = xs
+    def body(carry, xs):
+        hh, layer, ck, cv = carry
+        blk, ek, ev = xs
         a_in = rms_norm(hh, blk["ln1"])
-        a, (ck, cv) = attention_decode(blk["attn"], a_in, ck, cv, pos,
-                                       _acfg(cfg, True), shd)
+        a, (ck, cv) = attention_decode(blk["attn"], a_in, ck, cv, layer,
+                                       pos, _acfg(cfg, True), shd)
         hh = hh + a
         x_in = rms_norm(hh, blk["ln_x"])
         hh = hh + _cross_attention(blk["xattn"], x_in,
                                    ek.astype(hh.dtype),
                                    ev.astype(hh.dtype), cfg)
         hh = hh + mlp(blk["mlp"], rms_norm(hh, blk["ln2"]), cfg.act, shd)
-        return hh, (ck, cv)
+        return (hh, layer + 1, ck, cv), None
 
-    h, (k_new, v_new) = jax.lax.scan(
-        body, h, (params["dec_blocks"], cache["k"], cache["v"],
-                  cache["ek"], cache["ev"]))
+    (h, _, k_new, v_new), _ = jax.lax.scan(
+        body, (h, jnp.zeros((), jnp.int32), cache["k"], cache["v"]),
+        (params["dec_blocks"], cache["ek"], cache["ev"]))
     new_cache = dict(cache)
     new_cache["k"], new_cache["v"] = k_new, v_new
     new_cache["pos"] = pos + 1

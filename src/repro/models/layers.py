@@ -25,6 +25,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro import obs
 from repro.distributed.sharding import Sharder
@@ -33,7 +34,9 @@ from repro.models.params import Param, param
 __all__ = ["cast", "count_cast_bytes", "repeated", "traced_count",
            "FLOAT32_READS", "rms_norm",
            "make_rope", "apply_rope", "init_attention", "attention",
-           "attention_decode", "init_mla", "mla_attention", "mla_decode",
+           "attention_decode", "cache_seq_axis", "stored_order",
+           "write_position", "read_layer", "init_mla", "mla_attention",
+           "mla_decode",
            "init_mlp", "mlp", "causal_mask_bias", "AttnConfig",
            "set_tuned_layers", "use_tuned_layers", "tuned_layers_enabled"]
 
@@ -290,9 +293,10 @@ def _sdpa(q, k, v, bias, scale):
     return out.astype(q.dtype)
 
 
-def _sdpa_decode(q, cache_k, cache_v, bias, scale):
-    """q: (B,1,H,hd), cache_k/v: (B,S,KV,hd) — one-token GQA attention
-    that reads the cache as stored.
+def _sdpa_decode(q, cache_k, cache_v, bias, scale, seq: int):
+    """q: (B,1,H,hd), cache_k/v: one layer's cache as stored, (B,KV,S,hd)
+    or (B,KV,hd,S) (the sequence at ``seq``, `cache_seq_axis`) — one-
+    token GQA attention that reads the cache as stored.
 
     Each KV head scores its group of H//KV query heads in one grouped
     einsum over the cache in its own dtype, accumulated in f32: no
@@ -301,18 +305,21 @@ def _sdpa_decode(q, cache_k, cache_v, bias, scale):
     `_sdpa`).  The KV axis stays outermost in the group split, so a
     heads-sharded q meets the kv-head-sharded cache group for group.
     Bias and softmax in f32, PV in the value dtype with f32
-    accumulation."""
+    accumulation.  The cache is each dot's first operand, in either
+    stored order the form in which the CPU backend too takes bf16
+    operands to an f32 result."""
     b, sq, h, hd = q.shape
-    kvh = cache_k.shape[2]
+    kvh = cache_k.shape[1]
+    c = "".join(stored_order("bskd", seq, at=1))
     traced_count("attn.decode_grouped", 1)
     qg = q.reshape(b, sq, kvh, h // kvh, hd)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, cache_k,
+    logits = jnp.einsum(f"{c},bqkgd->bkgqs", cache_k, qg,
                         preferred_element_type=jnp.float32) * scale
     logits = logits + bias  # bias: (q, s) broadcast
     probs = jax.nn.softmax(logits, axis=-1).astype(cache_v.dtype)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, cache_v,
+    out = jnp.einsum(f"{c},bkgqs->bqkgd", cache_v, probs,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, sq, h, cache_v.shape[-1]).astype(q.dtype)
+    return out.reshape(b, sq, h, hd).astype(q.dtype)
 
 
 def _sdpa_chunked(q, k, v, q_positions, k_positions, window, scale,
@@ -410,19 +417,75 @@ def attention(p: Dict, x: jax.Array, cfg: AttnConfig, shd: Sharder,
     return y
 
 
+LANES = 128   # the minor axis of a TPU vector tile
+
+
+def cache_seq_axis(width: int) -> int:
+    """Where the sequence axis sits in a stored decode-cache leaf whose
+    positions each hold ``width`` values per head, counted from the end.
+
+    Second from last where ``width`` fills whole lane tiles: (L, B,
+    *heads, S, width), each position one row of its tiles.  Else last:
+    (L, B, *heads, width, S), so that no tile pads a narrow row out to
+    the lanes.  The device lays either out row-major, as prefill hands
+    it over; the decode step writes one position into it and reads a
+    layer's slice of it in that layout (`write_position`)."""
+    return -2 if width % LANES == 0 else -1
+
+
+def stored_order(axes, seq: int, at: int = 2) -> tuple:
+    """``axes`` (a shape, logical dims or einsum letters) of a cache
+    whose sequence axis is ``at``, with that axis moved to ``seq``
+    (`cache_seq_axis`): the stored order."""
+    rest = list(axes[:at]) + list(axes[at + 1:])
+    rest.insert(len(rest) + 1 + seq, axes[at])
+    return tuple(rest)
+
+
+def write_position(stack: jax.Array, new: jax.Array, layer, slot,
+                   seq: int, shd: Sharder, dims) -> jax.Array:
+    """``stack`` (L, B, ...) in its stored order with ``new`` (B, 1,
+    *per-position) written at layer ``layer``, position ``slot`` of the
+    sequence axis ``seq``: an update in place of one position where the
+    stack is a loop's carry or a donated buffer.  ``dims`` are the
+    stack's logical dims as (L, B, S, *per-position).
+
+    The result keeps the stack's row-major layout: left free, XLA lays
+    a carried stack out for this write (the written axes minor) and
+    copies the whole stack into and out of the loop.  Only where the
+    program is not partitioned: XLA's partitioner replicates a layout
+    constraint, gathering the whole stack at every layer."""
+    new = jnp.moveaxis(new, 1, seq)[None].astype(stack.dtype)
+    idx = [0] * stack.ndim
+    idx[0], idx[stack.ndim + seq] = layer, slot
+    out = jax.lax.dynamic_update_slice(stack, new, idx)
+    if shd.mesh is None:
+        out = with_layout_constraint(
+            out, Layout(major_to_minor=tuple(range(stack.ndim))))
+    return shd.cache(out, stored_order(dims, seq))
+
+
+def read_layer(stack: jax.Array, layer) -> jax.Array:
+    """Layer ``layer`` of a stacked state (L, B, ...)."""
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
 def attention_decode(p: Dict, x: jax.Array, cache_k: jax.Array,
-                     cache_v: jax.Array, pos: jax.Array, cfg: AttnConfig,
-                     shd: Sharder, window_override=None,
+                     cache_v: jax.Array, layer, pos: jax.Array,
+                     cfg: AttnConfig, shd: Sharder, window_override=None,
                      rolling: bool = False):
-    """One-token decode.  x: (B, 1, D); cache_k/v: (B, S_cache, KV, hd);
-    ``pos``: scalar int32 current position.
+    """One-token decode.  x: (B, 1, D); cache_k/v: the stacked caches
+    (L, B, KV, S, hd) or (L, B, KV, hd, S) (`cache_seq_axis`), of which
+    layer ``layer`` is written at one position and read; ``pos``: scalar
+    int32 current position.  Returns y and the updated stacks.
 
     ``rolling=True`` treats the cache as a mod-S_cache ring buffer
     (windowed layers / capped long-context decode); the effective
     attention span is ``min(window, S_cache)``.  ``window_override`` may
     be traced (per-layer windows in the hybrid family)."""
     b = x.shape[0]
-    s_cache = cache_k.shape[1]
+    seq = cache_seq_axis(cfg.head_dim)
+    s_cache = cache_k.shape[seq]
     window = cfg.window if window_override is None else window_override
     positions = jnp.full((b, 1), pos, dtype=jnp.int32)
     q, k_new, v_new = _project_qkv(p, x, cfg, positions)
@@ -431,14 +494,11 @@ def attention_decode(p: Dict, x: jax.Array, cache_k: jax.Array,
             k_new = jnp.repeat(k_new, cfg.kv_repeat, axis=2)
             v_new = jnp.repeat(v_new, cfg.kv_repeat, axis=2)
         slot = (pos % s_cache) if rolling else pos
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k_new.astype(cache_k.dtype), (0, slot, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v_new.astype(cache_v.dtype), (0, slot, 0, 0))
-        cache_k = shd.cache(cache_k, ("batch", "cache_seq", "kv_heads",
-                                      "head_dim"))
-        cache_v = shd.cache(cache_v, ("batch", "cache_seq", "kv_heads",
-                                      "head_dim"))
+        dims = ("layers", "batch", "cache_seq", "kv_heads", "head_dim")
+        cache_k = write_position(cache_k, k_new, layer, slot, seq, shd,
+                                 dims)
+        cache_v = write_position(cache_v, v_new, layer, slot, seq, shd,
+                                 dims)
     with obs.scope("core"):
         idx = jnp.arange(s_cache, dtype=jnp.int32)
         if rolling:
@@ -458,7 +518,8 @@ def attention_decode(p: Dict, x: jax.Array, cache_k: jax.Array,
                 ok &= (pos - idx) < window
             bias = jnp.where(ok, 0.0, -1e30).astype(jnp.float32)[None, :]
         scale = 1.0 / math.sqrt(cfg.head_dim)
-        out = _sdpa_decode(q, cache_k, cache_v, bias, scale)
+        out = _sdpa_decode(q, read_layer(cache_k, layer),
+                           read_layer(cache_v, layer), bias, scale, seq)
     with obs.scope("out"):
         y = jnp.einsum("bshk,hkd->bsd", out, cast(p["wo"], x.dtype))
     return y, (cache_k, cache_v)
@@ -552,39 +613,46 @@ def mla_attention(p: Dict, x: jax.Array, cfg: AttnConfig, shd: Sharder,
 
 
 def mla_decode(p: Dict, x: jax.Array, c_cache: jax.Array,
-               r_cache: jax.Array, pos: jax.Array, cfg: AttnConfig,
+               r_cache: jax.Array, layer, pos: jax.Array, cfg: AttnConfig,
                shd: Sharder):
     """One-token latent attention in the absorbed form.  x: (B, 1, D);
-    c_cache (B, S, kv_lora_rank), r_cache (B, S, rope_dim); ``pos`` the
-    scalar position written.  The query's position-free part is taken
-    through ``wk_b`` into the latent, scored against the cached latent
-    (and its rope part against the shared rope key); the weighted sum of
-    latents goes through ``wv_b`` afterwards: no head is decompressed."""
-    b, s_cache = x.shape[0], c_cache.shape[1]
+    c_cache (L, B, S, kv_lora_rank) and r_cache (L, B, S, rope_dim), each
+    in its stored order (`cache_seq_axis`), of which layer ``layer`` is
+    written and read; ``pos`` the scalar position written.  The query's
+    position-free part is taken through ``wk_b`` into the latent, scored
+    against the cached latent (and its rope part against the shared
+    rope key); the weighted sum of latents goes through ``wv_b``
+    afterwards: no head is decompressed."""
+    c_seq = cache_seq_axis(cfg.kv_lora_rank)
+    r_seq = cache_seq_axis(cfg.rope_dim)
+    b, s_cache = x.shape[0], c_cache.shape[c_seq]
     nope = cfg.head_dim
     positions = jnp.full((b, 1), pos, dtype=jnp.int32)
     q, c_new, r_new = _mla_project(p, x, cfg, positions)
     with obs.scope("cache_write"):
-        c_cache = jax.lax.dynamic_update_slice(
-            c_cache, c_new.astype(c_cache.dtype), (0, pos, 0))
-        r_cache = jax.lax.dynamic_update_slice(
-            r_cache, r_new.astype(r_cache.dtype), (0, pos, 0))
-        c_cache = shd.cache(c_cache, ("batch", "cache_seq", None))
-        r_cache = shd.cache(r_cache, ("batch", "cache_seq", None))
+        dims = ("layers", "batch", "cache_seq", None)
+        c_cache = write_position(c_cache, c_new, layer, pos, c_seq, shd,
+                                 dims)
+        r_cache = write_position(r_cache, r_new, layer, pos, r_seq, shd,
+                                 dims)
     with obs.scope("absorb"):
         q_lat = jnp.einsum("bshk,chk->bshc", q[..., :nope],
                            cast(p["wk_b"], x.dtype))
     with obs.scope("core"):
+        c_layer, r_layer = read_layer(c_cache, layer), read_layer(r_cache,
+                                                                  layer)
+        c = "".join(stored_order("btc", c_seq, at=1))
+        r = "".join(stored_order("btr", r_seq, at=1))
         f32 = jnp.float32
-        logits = (jnp.einsum("bshc,btc->bhst", q_lat, c_cache,
+        logits = (jnp.einsum(f"{c},bshc->bhst", c_layer, q_lat,
                              preferred_element_type=f32)
-                  + jnp.einsum("bshr,btr->bhst", q[..., nope:], r_cache,
+                  + jnp.einsum(f"{r},bshr->bhst", r_layer, q[..., nope:],
                                preferred_element_type=f32))
         logits = logits / math.sqrt(nope + cfg.rope_dim)
         ok = jnp.arange(s_cache, dtype=jnp.int32) <= pos
         logits = jnp.where(ok, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(c_cache.dtype)
-        o_lat = jnp.einsum("bhst,btc->bshc", probs, c_cache)
+        probs = jax.nn.softmax(logits, axis=-1).astype(c_layer.dtype)
+        o_lat = jnp.einsum(f"bhst,{c}->bshc", probs, c_layer)
     with obs.scope("unabsorb"):
         o = jnp.einsum("bshc,chk->bshk", o_lat, cast(p["wv_b"], x.dtype))
     with obs.scope("out"):

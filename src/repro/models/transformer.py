@@ -20,10 +20,11 @@ from repro import obs
 from repro.distributed.sharding import Sharder
 from repro.models.config import ModelConfig
 from repro.models.layers import (FLOAT32_READS, AttnConfig, attention,
-                                 attention_decode, cast, count_cast_bytes,
+                                 attention_decode, cache_seq_axis, cast,
+                                 count_cast_bytes, stored_order,
                                  init_attention, init_mla, init_mlp,
-                                 mla_attention, mla_decode, mlp, repeated,
-                                 rms_norm)
+                                 mla_attention, mla_decode, mlp, read_layer,
+                                 repeated, rms_norm)
 from repro.models.moe import init_moe, moe_layer
 from repro.models.params import Param, param, stack_dims, tree_param_bytes
 from repro.models.ssd import (SsdConfig, init_ssd, init_ssd_state,
@@ -56,6 +57,18 @@ def attn_state(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
                 "k_rope": (cfg.qk_rope_head_dim,)}
     kv = cfg.n_kv * max(cfg.kv_repeat, 1)
     return {"k": (kv, cfg.hd), "v": (kv, cfg.hd)}
+
+
+def cache_dims(cfg: ModelConfig) -> Dict[str, Tuple[Optional[str], ...]]:
+    """Name -> logical dims of each stacked attention leaf of the decode
+    cache, in its stored axis order (`cache_seq_axis`)."""
+    per = (None,) if cfg.kv_lora_rank else ("kv_heads", "head_dim")
+    out = {}
+    for name, shape in attn_state(cfg).items():
+        dims = stored_order(("layers", "batch", "cache_seq") + per,
+                           cache_seq_axis(shape[-1]))
+        out[name] = out[name + "_pre"] = dims
+    return out
 
 
 def ssd_config(cfg: ModelConfig) -> SsdConfig:
@@ -397,10 +410,12 @@ def init_lm_cache(cfg: ModelConfig, batch: int, seq_len: int,
     if cfg.family in ("dense", "moe", "hybrid"):
         per_position = 0
         for name, shape in attn_state(cfg).items():
-            cache[name] = jnp.zeros((n_main, batch, sc) + shape, dtype)
+            seq = cache_seq_axis(shape[-1])
+            cache[name] = jnp.zeros(
+                stored_order((n_main, batch, sc) + shape, seq), dtype)
             if cfg.first_dense_layers:
-                cache[name + "_pre"] = jnp.zeros(
-                    (cfg.first_dense_layers, batch, sc) + shape, dtype)
+                cache[name + "_pre"] = jnp.zeros(stored_order(
+                    (cfg.first_dense_layers, batch, sc) + shape, seq), dtype)
             per_position += int(np.prod(shape)) * jnp.dtype(dtype).itemsize
         obs.gauge("serve.cache_bytes_per_token",
                   per_position * (n_main + cfg.first_dense_layers))
@@ -415,33 +430,51 @@ def init_lm_cache(cfg: ModelConfig, batch: int, seq_len: int,
     return cache
 
 
-def _block_decode(blk, h, win, ck, cv, sstate, pos, cfg: ModelConfig,
+def _cache_positions(cfg: ModelConfig, cache: Dict) -> int:
+    """The positions the decode cache's attention leaves hold (0 without
+    attention)."""
+    for name, shape in attn_state(cfg).items():
+        if name in cache:
+            return cache[name].shape[cache_seq_axis(shape[-1])]
+    return 0
+
+
+def _block_decode(blk, h, win, state, layer, pos, cfg: ModelConfig,
                   shd: Sharder, moe: bool):
+    """One layer of a decode step over the stacked decode ``state``
+    (attention state under `attn_state`'s names, ``ssm``, ``conv``):
+    returns h and the state with layer ``layer`` updated in place."""
     acfg = attn_config(cfg)
     fam = cfg.family
-    if fam == "ssm":
-        x = rms_norm(h, blk["ln1"], cfg.norm_eps)
+    state = dict(state)
+
+    def ssm(x):
         with obs.scope("ssm"):
-            y, sstate = ssd_decode(blk["ssd"], x, sstate, ssd_config(cfg),
-                                   shd)
-        return h + y, (ck, cv, sstate)
+            rows = {n: read_layer(state[n], layer) for n in ("ssm", "conv")}
+            y, rows = ssd_decode(blk["ssd"], x, rows, ssd_config(cfg), shd)
+            for n, row in rows.items():
+                state[n] = jax.lax.dynamic_update_index_in_dim(
+                    state[n], row.astype(state[n].dtype), layer, 0)
+        return y
+
     x = rms_norm(h, blk["ln1"], cfg.norm_eps)
+    if fam == "ssm":
+        return h + ssm(x), state
+    kn, vn = attn_state(cfg)
     with obs.scope("attn"):
         if cfg.kv_lora_rank:
-            a, (ck, cv) = mla_decode(blk["attn"], x, ck, cv, pos, acfg, shd)
+            a, (state[kn], state[vn]) = mla_decode(
+                blk["attn"], x, state[kn], state[vn], layer, pos, acfg, shd)
         else:
-            a, (ck, cv) = attention_decode(blk["attn"], x, ck, cv, pos,
-                                           acfg, shd, window_override=win,
-                                           rolling=(fam == "hybrid"))
+            a, (state[kn], state[vn]) = attention_decode(
+                blk["attn"], x, state[kn], state[vn], layer, pos, acfg, shd,
+                window_override=win, rolling=(fam == "hybrid"))
     if fam == "hybrid":
-        with obs.scope("ssm"):
-            m, sstate = ssd_decode(blk["ssd"], x, sstate, ssd_config(cfg),
-                                   shd)
-        h = h + _mix(blk, a, m, h.dtype)
+        h = h + _mix(blk, a, ssm(x), h.dtype)
     else:
         h = h + a
     y, _ = _ffn(blk, rms_norm(h, blk["ln2"], cfg.norm_eps), cfg, shd, moe)
-    return h + y, (ck, cv, sstate)
+    return h + y, state
 
 
 def lm_decode_step(params: Dict, cache: Dict, token: jax.Array,
@@ -471,53 +504,44 @@ def _decode_step(params: Dict, cache: Dict, token: jax.Array,
     new_cache = dict(cache)
     fam = cfg.family
 
-    def scan_stack(blocks, h, windows, k, v, ssm, conv, moe):
-        has_attn = k is not None
-        has_ssm = ssm is not None
+    def scan_stack(blocks, h, windows, state, moe):
+        """The layer scan: the carry holds h, the layer index and the
+        stacked ``state``, which each layer updates in place; the scanned
+        inputs are the layer weights and windows alone."""
+        obs.count("decode.cache_in_place", len(state))
 
-        def body(hh, xs):
-            blk, win, ck, cv, s_ssm, s_conv = xs
-            sstate = {"ssm": s_ssm, "conv": s_conv} if has_ssm else None
+        def body(carry, xs):
+            hh, layer, st = carry
+            blk, win = xs
             with obs.scope("layer"):
-                hh, (ck, cv, sstate) = _block_decode(
-                    blk, hh, win, ck, cv, sstate, pos, cfg, shd, moe)
-            ys = (ck if has_attn else 0,
-                  cv if has_attn else 0,
-                  sstate["ssm"] if has_ssm else 0,
-                  sstate["conv"] if has_ssm else 0)
-            return hh, ys
+                hh, st = _block_decode(blk, hh, win, st, layer, pos, cfg,
+                                       shd, moe)
+            return (hh, layer + 1, st), None
 
         n = windows.shape[0]
-        zeros = jnp.zeros((n,), jnp.int32)
-        xs = (blocks, windows,
-              k if has_attn else zeros, v if has_attn else zeros,
-              ssm if has_ssm else zeros, conv if has_ssm else zeros)
         with repeated(n):
-            h, ys = jax.lax.scan(body, h, xs)
-        return h, ys
+            (h, _, state), _ = jax.lax.scan(
+                body, (h, jnp.zeros((), jnp.int32), state), (blocks, windows))
+        return h, state
 
     n_main = (cfg.n_layers - cfg.first_dense_layers
               if fam == "moe" else cfg.n_layers)
-    kn, vn = attn_state(cfg)
-    sc = cache[kn].shape[2] if kn in cache else 0
+    names = [n for n in attn_state(cfg) if n in cache]
+    sc = _cache_positions(cfg, cache)
     if "prefix_blocks" in params:
         npre = cfg.first_dense_layers
-        h, ys = scan_stack(_overlay(params["prefix_blocks"],
-                                    carried.get("prefix_blocks")), h,
-                           jnp.full((npre,), max(sc, 1), jnp.int32),
-                           cache[kn + "_pre"], cache[vn + "_pre"], None,
-                           None, moe=False)
-        new_cache[kn + "_pre"], new_cache[vn + "_pre"] = ys[0], ys[1]
+        h, pre = scan_stack(_overlay(params["prefix_blocks"],
+                                     carried.get("prefix_blocks")), h,
+                            jnp.full((npre,), max(sc, 1), jnp.int32),
+                            {n: cache[n + "_pre"] for n in names},
+                            moe=False)
+        new_cache.update({n + "_pre": x for n, x in pre.items()})
     windows = hybrid_windows(cfg, max(sc, 1), n_main)
-    h, ys = scan_stack(_overlay(params["blocks"], carried.get("blocks")),
-                       h, windows,
-                       cache.get(kn), cache.get(vn),
-                       cache.get("ssm"), cache.get("conv"),
-                       moe=(fam == "moe"))
-    if kn in cache:
-        new_cache[kn], new_cache[vn] = ys[0], ys[1]
-    if "ssm" in cache:
-        new_cache["ssm"], new_cache["conv"] = ys[2], ys[3]
+    h, state = scan_stack(
+        _overlay(params["blocks"], carried.get("blocks")), h, windows,
+        {n: cache[n] for n in names + ["ssm", "conv"] if n in cache},
+        moe=(fam == "moe"))
+    new_cache.update(state)
     new_cache["pos"] = pos + 1
     return _head(params, h, cfg), new_cache
 
@@ -555,36 +579,31 @@ def _fill_cache(cfg: ModelConfig, b: int, s: int, max_len: int, pre_ys,
     """The decode cache, sized for ``max_len``, holding the attention and
     SSM states that a prefill of ``s`` positions collected."""
     cache = init_lm_cache(cfg, b, max_len)
-    kn, vn = attn_state(cfg)
 
-    def fill_kv(kvs, kname, vname):
-        k, v = kvs  # (L, B, S, ...)
-        sc = cache[kname].shape[2]
-        if sc == s:
-            cache[kname] = k.astype(cache[kname].dtype)
-            cache[vname] = v.astype(cache[vname].dtype)
-        elif sc > s:
-            cache[kname] = cache[kname].at[:, :, :s].set(
-                k.astype(cache[kname].dtype))
-            cache[vname] = cache[vname].at[:, :, :s].set(
-                v.astype(cache[vname].dtype))
-        else:
+    def fill(name, x, width):
+        """x (L, B, s, ..., width) from prefill into leaf ``name``, in its
+        stored order."""
+        seq = cache_seq_axis(width)
+        sc = cache[name].shape[seq]
+        if sc < s:
             # capped ring cache: position p lives at slot p % sc; the
             # last sc positions land at roll(linear_tail, s % sc).
-            shift = s % sc
-            cache[kname] = jnp.roll(k[:, :, -sc:], shift, axis=2
-                                    ).astype(cache[kname].dtype)
-            cache[vname] = jnp.roll(v[:, :, -sc:], shift, axis=2
-                                    ).astype(cache[vname].dtype)
+            x = jnp.roll(x[:, :, -sc:], s % sc, axis=2)
+        x = jnp.moveaxis(x.astype(cache[name].dtype), 2, seq)
+        if sc > s:
+            idx = [slice(None)] * x.ndim
+            idx[seq] = slice(0, s)
+            x = cache[name].at[tuple(idx)].set(x)
+        cache[name] = x
 
-    if main_ys is not None:
-        kvs, sstates = main_ys
-        if kvs is not None and kn in cache:
-            fill_kv(kvs, kn, vn)
-        if sstates is not None and "ssm" in cache:
-            cache["ssm"] = sstates["ssm"].astype(cache["ssm"].dtype)
-            cache["conv"] = sstates["conv"].astype(cache["conv"].dtype)
-    if pre_ys is not None and pre_ys[0] is not None and kn + "_pre" in cache:
-        fill_kv(pre_ys[0], kn + "_pre", vn + "_pre")
+    for suffix, ys in (("", main_ys), ("_pre", pre_ys)):
+        kvs = ys[0] if ys is not None else None
+        for (name, shape), x in zip(attn_state(cfg).items(), kvs or ()):
+            if name + suffix in cache:
+                fill(name + suffix, x, shape[-1])
+    sstates = main_ys[1] if main_ys is not None else None
+    if sstates is not None and "ssm" in cache:
+        for name in ("ssm", "conv"):
+            cache[name] = sstates[name].astype(cache[name].dtype)
     cache["pos"] = jnp.asarray(s, jnp.int32)
     return cache

@@ -5,8 +5,10 @@ the cache in its own dtype (`layers._sdpa_decode`), where `_sdpa`
 broadcasts K/V up to every query head and casts K to float32.  These
 tests hold the grouped core to `_sdpa` on seeded inputs (GQA, MHA and a
 replicated cache; rolling and linear caches under a traced window;
-float32 and bf16), check that the decode program broadcasts no cache up
-to the query heads and casts none to float32, and that the
+float32 and bf16; a head width that fills the lanes, stored with the
+sequence second from last, and one that does not, stored with it
+last), check that the decode program broadcasts no cache up to the
+query heads and casts none to float32, and that the
 ``attn.decode_grouped`` counter reads one per attention layer of a
 traced decode step.
 """
@@ -22,10 +24,13 @@ from repro.configs import get_smoke
 from repro.distributed import make_serve_fns
 from repro.distributed.sharding import Sharder
 from repro.models import build_model, layers
-from repro.models.layers import AttnConfig, attention_decode, init_attention
+from repro.models.layers import (AttnConfig, attention_decode,
+                                 cache_seq_axis, init_attention,
+                                 stored_order)
 
 SHD = Sharder()
 B, S_CACHE, HD, D = 2, 16, 16, 32
+HDS = [16, 128]   # stored (L, B, KV, hd, S) and (L, B, KV, S, hd)
 SEED = 1616
 
 # (query heads, KV heads, kv_repeat): hymba-1.5b's 25 over 5,
@@ -40,13 +45,15 @@ CACHES = {"linear": (False, 11, 6), "rolling": (True, 21, 9)}
 TOL = {"float32": 1e-5, "bfloat16": 2e-2}
 
 
-def decode_inputs(h, kv, r, dtype):
-    cfg = AttnConfig(d_model=D, n_heads=h, n_kv=kv, head_dim=HD,
+def decode_inputs(h, kv, r, dtype, hd=HD):
+    """Config, weights, x, and a one-layer stack of K and V in their
+    stored order."""
+    cfg = AttnConfig(d_model=D, n_heads=h, n_kv=kv, head_dim=hd,
                      kv_repeat=r)
     k = jax.random.split(jax.random.PRNGKey(SEED), 4)
     p = init_attention(k[0], cfg)
     x = jax.random.normal(k[1], (B, 1, D)).astype(dtype)
-    shape = (B, S_CACHE, kv * r, HD)
+    shape = stored_order((1, B, S_CACHE, kv * r, hd), cache_seq_axis(hd))
     ck = jax.random.normal(k[2], shape).astype(dtype)
     cv = jax.random.normal(k[3], shape).astype(dtype)
     return cfg, p, x, ck, cv
@@ -54,21 +61,30 @@ def decode_inputs(h, kv, r, dtype):
 
 def run_decode(cfg, p, x, ck, cv, pos, window, rolling):
     fn = jax.jit(lambda p, x, ck, cv, pos, win: attention_decode(
-        p, x, ck, cv, pos, cfg, SHD, window_override=win, rolling=rolling))
+        p, x, ck, cv, 0, pos, cfg, SHD, window_override=win,
+        rolling=rolling))
     return fn(p, x, ck, cv, jnp.int32(pos), jnp.int32(window))
 
 
+def broadcast_core(q, k, v, bias, scale, seq):
+    """`_sdpa` over one layer's stored cache, its sequence moved back
+    next to the batch."""
+    return layers._sdpa(q, jnp.moveaxis(k, seq, 1), jnp.moveaxis(v, seq, 1),
+                        bias, scale)
+
+
+@pytest.mark.parametrize("hd", HDS)
 @pytest.mark.parametrize("dtype", list(TOL))
 @pytest.mark.parametrize("cache", list(CACHES))
 @pytest.mark.parametrize("heads", list(HEADS))
 def test_grouped_core_matches_the_broadcast_core(monkeypatch, heads, cache,
-                                                 dtype):
+                                                 dtype, hd):
     h, kv, r = HEADS[heads]
     rolling, pos, window = CACHES[cache]
-    cfg, p, x, ck, cv = decode_inputs(h, kv, r, jnp.dtype(dtype))
+    cfg, p, x, ck, cv = decode_inputs(h, kv, r, jnp.dtype(dtype), hd)
     y, (k1, v1) = run_decode(cfg, p, x, ck, cv, pos, window, rolling)
     with monkeypatch.context() as m:
-        m.setattr(layers, "_sdpa_decode", layers._sdpa)
+        m.setattr(layers, "_sdpa_decode", broadcast_core)
         y_ref, (k0, v0) = run_decode(cfg, p, x, ck, cv, pos, window,
                                      rolling)
     assert y.dtype == y_ref.dtype == jnp.dtype(dtype)
@@ -93,10 +109,10 @@ def cache_broadcasts_and_casts(h, kv):
     `attention_decode`."""
     cfg, p, x, ck, cv = decode_inputs(h, kv, 1, jnp.bfloat16)
     jaxpr = jax.make_jaxpr(lambda p, x, ck, cv, pos, win: attention_decode(
-        p, x, ck, cv, pos, cfg, SHD, window_override=win, rolling=True))(
+        p, x, ck, cv, 0, pos, cfg, SHD, window_override=win, rolling=True))(
         p, x, ck, cv, jnp.int32(3), jnp.int32(8)).jaxpr
     up = {(B, S_CACHE, kv, h // kv, HD), (B, S_CACHE, h, HD)}
-    cache_shapes = up | {ck.shape}
+    cache_shapes = up | {ck.shape, ck.shape[1:]}
     broadcasts, casts = [], []
     for eqn in _eqns(jaxpr):
         out = eqn.outvars[0].aval
@@ -114,7 +130,7 @@ def test_decode_reads_the_cache_unbroadcast_and_uncast(monkeypatch):
     to float32; the same check finds both in the broadcast core."""
     assert cache_broadcasts_and_casts(25, 5) == ([], [])
     with monkeypatch.context() as m:
-        m.setattr(layers, "_sdpa_decode", layers._sdpa)
+        m.setattr(layers, "_sdpa_decode", broadcast_core)
         broadcasts, casts = cache_broadcasts_and_casts(25, 5)
     assert len(broadcasts) == 2 and len(casts) == 1
 

@@ -17,6 +17,7 @@ carried copy and is not to be rewritten after it.  The
 came in (latent attention and the held-experts layer), from the program
 that introduced it, with the carried copy.
 """
+import ast
 import functools
 import json
 import os
@@ -125,26 +126,52 @@ def lowered_state(arch: str, stored: str, max_len: int = 0):
     return model, params, cache
 
 
+ATTENTION_LEAVES = {"k", "v", "c_kv", "k_rope"}
+
+
+def in_stored_order(recorded: list) -> list:
+    """The recorded structure with each attention leaf of the decode
+    cache, recorded as (L, B, S, *per-position), in its stored axis
+    order: the sequence after the heads, second from last where a
+    head's width fills whole 128-lane tiles, else last."""
+    out = []
+    for line in recorded:
+        path, rest = line.split(" ", 1)
+        name = path.strip("[]'").removesuffix("_pre")
+        if name in ATTENTION_LEAVES:
+            shape = rest[:rest.index(")") + 1]
+            dims = list(ast.literal_eval(shape))
+            s = dims.pop(2)
+            dims.insert(len(dims) - (dims[-1] % 128 == 0), s)
+            line = f"{path} {tuple(dims)}{rest[len(shape):]}"
+        out.append(line)
+    return out
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_bf16_stored_params_carry_no_copy(parent, arch):
     """Stored in the compute dtype, the state and its structure are the
-    parent's, and prefill hands nothing over."""
+    parent's, each attention leaf in its stored axis order, and prefill
+    hands nothing over."""
     _, _, cache = lowered_state(arch, "bfloat16", PROMPT + STEPS)
     assert "weights" not in cache
-    assert structure(cache) == parent["cache_structure"][f"{arch}/bfloat16"]
+    assert structure(cache) == in_stored_order(
+        parent["cache_structure"][f"{arch}/bfloat16"])
     assert obs.counters().get("serve.carried_weight_bytes", 0) == 0
 
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_float32_stored_params_carry_the_bf16_layer_weights(parent, arch):
-    """Stored in float32, the state is the parent's plus a bf16 copy of
-    every layer weight the layers read in bf16, under its own path; the
-    leaves read at float32 stay out of it."""
+    """Stored in float32, the state is the parent's (each attention leaf
+    in its stored axis order) plus a bf16 copy of every layer weight the
+    layers read in bf16, under its own path; the leaves read at float32
+    stay out of it."""
     from repro.models.layers import FLOAT32_READS
     from repro.models.params import Param, tree_param_bytes
     model, params, cache = lowered_state(arch, "float32", PROMPT + STEPS)
     rest = {k: v for k, v in cache.items() if k != "weights"}
-    assert structure(rest) == parent["cache_structure"][f"{arch}/float32"]
+    assert structure(rest) == in_stored_order(
+        parent["cache_structure"][f"{arch}/float32"])
     carried = cache["weights"]
     assert obs.counters()["serve.carried_weight_bytes"] \
         == tree_param_bytes(carried) > 0

@@ -21,7 +21,7 @@ from repro.configs import get_config, get_smoke
 from repro.distributed import make_serve_fns
 from repro.distributed.sharding import Sharder
 from repro.models import build_model, moe
-from repro.models.layers import mla_attention, mla_decode
+from repro.models.layers import cache_seq_axis, mla_attention, mla_decode
 from repro.models.params import Param
 from repro.models.transformer import attn_config
 
@@ -105,9 +105,15 @@ def test_absorbed_decode_matches_the_decompressed_form():
         _, (c_kv, k_rope) = mla_attention(p, x[:, :s], acfg, SHD,
                                           return_kv=True)
         pad = ((0, 0), (0, 5), (0, 0))          # cache longer than filled
-        y, (c2, r2) = mla_decode(p, x[:, s:], jnp.pad(c_kv, pad),
-                                 jnp.pad(k_rope, pad), jnp.int32(s), acfg,
-                                 SHD)
+        c_seq = cache_seq_axis(acfg.kv_lora_rank)
+        r_seq = cache_seq_axis(acfg.rope_dim)
+        y, (c2, r2) = mla_decode(
+            p, x[:, s:], jnp.moveaxis(jnp.pad(c_kv, pad), 1, c_seq)[None],
+            jnp.moveaxis(jnp.pad(k_rope, pad), 1, r_seq)[None], 0,
+            jnp.int32(s), acfg, SHD)
+        # back from the stored one-layer stacks to (B, S, width)
+        c2 = jnp.moveaxis(c2[0], c_seq, 1)
+        r2 = jnp.moveaxis(r2[0], r_seq, 1)
     np.testing.assert_allclose(np.asarray(y[:, 0]), np.asarray(full[:, s]),
                                atol=1e-5, rtol=1e-5)
     # the step wrote its own latent and rope key at position s, and only there

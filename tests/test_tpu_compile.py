@@ -310,3 +310,91 @@ def test_decode_reads_the_weights_prefill_hands_over_on_one_v5e(
     handed = jax.tree.leaves(pc.output_formats[1]["weights"])
     taken = jax.tree.leaves(dc.input_formats[0][1]["weights"])
     assert [f.layout for f in taken] == [f.layout for f in handed]
+
+
+@pytest.mark.parametrize("arch,changes,batch,prompt_len,gen,leaves", [
+    ("hymba-1.5b", {}, 32, 256, 512, 4),
+    ("starcoder2-3b", {"n_layers": 15}, 2, 3840, 32, 2),
+    # the prefix layer and two expert layers: every cache leaf's shape but
+    # the layer count, at the cell's batch and length
+    ("moonlight-16b-a3b", {"n_layers": 3, "experts_held": (0, 16),
+                           "vocab": 40960}, 64, 1024, 512, 4),
+])
+def test_decode_updates_the_cache_in_place_on_one_v5e(
+        one_chip, monkeypatch, arch, changes, batch, prompt_len, gen,
+        leaves):
+    """The served decode step at a cell's cache shapes, compiled for one
+    v5e with the state donated, updates the stacked cache in place: no
+    copy or transpose has the shape of a stacked cache leaf or of one
+    layer's slice of it, decode takes each leaf in the layout prefill
+    hands it over in, its temporaries stay under the stacked cache's
+    bytes, and ``decode.cache_in_place`` counts every leaf it carries."""
+    import dataclasses
+    import functools
+    import re
+    import jax.numpy as jnp
+    from repro import obs, tuning_cache
+    from repro.distributed import make_serve_fns
+    from repro.models import build_model
+    from repro.models.layers import use_tuned_layers
+
+    cfg = dataclasses.replace(get_config(arch), **changes)
+    model = build_model(cfg)
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(model.abstract_params())
+    tokens = on_chip(jax.ShapeDtypeStruct((batch, prompt_len), jnp.int32))
+    prefill, decode = make_serve_fns(model)
+    prefill = functools.partial(prefill, max_len=prompt_len + gen)
+
+    def greedy(logits):
+        return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+
+    def serve_prefill(params, tokens):
+        logits, cache = prefill(params, {"tokens": tokens})
+        return greedy(logits), cache
+
+    def serve_decode(params, cache, tok):
+        logits, cache = decode(params, cache, tok)
+        return greedy(logits), cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    db = TuningDatabase()
+    tuning_cache.thaw()
+    tuning_cache.set_default_db(db)
+    try:
+        GraphTuner.tune_config(cfg, batch=batch, prompt_len=prompt_len, db=db)
+        tuning_cache.freeze()
+        with use_tuned_layers():
+            pc = jax.jit(serve_prefill).lower(params, tokens).compile()
+            tok, cache = on_chip(jax.eval_shape(serve_prefill, params, tokens))
+            obs.reset_counters("decode.")
+            dc = jax.jit(serve_decode, donate_argnums=(1,)).lower(
+                params, cache, tok).compile()
+            in_place = obs.counters().get("decode.cache_in_place")
+    finally:
+        tuning_cache.thaw()
+        tuning_cache.reset_default_db()
+        monkeypatch.undo()
+        jax.clear_caches()
+
+    names = [n for n in cache if n not in ("pos", "weights")]
+    assert len(names) == leaves == in_place
+    shapes = set()
+    for n in names:
+        shape = cache[n].shape
+        shapes |= {shape, shape[1:], (1,) + shape[1:]}
+    shapes = {",".join(map(str, s)) for s in shapes}
+    text = dc.as_text()
+    moved = [shape for shape, _ in re.findall(
+        r"= \w+\[([\d,]*)\]\{[^}]*\} (copy|transpose)\(", text)
+        if shape in shapes]
+    assert moved == [], moved
+    handed = pc.output_formats[1]
+    taken = dc.input_formats[0][1]
+    assert [taken[n].layout for n in names] \
+        == [handed[n].layout for n in names]
+    stacked = sum(cache[n].size * cache[n].dtype.itemsize for n in names)
+    assert dc.memory_analysis().temp_size_in_bytes < stacked
